@@ -1,56 +1,13 @@
-"""Render §Dry-run / §Roofline markdown tables from dryrun_results.jsonl,
-plus the sim-lattice perf trajectory from ``BENCH_history.jsonl`` (one
+"""Render the sim-lattice perf trajectory from ``BENCH_history.jsonl`` (one
 appended record per ``python -m benchmarks.run``, stamped with git SHA and
-timestamp — see ``benchmarks.run.append_history``)."""
+timestamp — see ``benchmarks.run.append_history``), and gate on it."""
 from __future__ import annotations
 
 import argparse
 import json
 import os
 
-from benchmarks.roofline import DEFAULT_JSON, load_records, roofline_terms
 from benchmarks.run import HISTORY_PATH
-
-
-def dryrun_table(recs) -> str:
-    lines = [
-        "| arch | shape | mesh | status | peak GiB/dev | HLO FLOPs (global) "
-        "| coll GiB/dev | params |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    for r in recs:
-        if r["status"] != "ok":
-            lines.append(
-                f"| {r['arch']} | {r['shape']} | {r['mesh']} | "
-                f"{r['status']} ({r.get('reason','')[:40]}…) | – | – | – | – |"
-            )
-            continue
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {r['mesh']} | ok | "
-            f"{r['memory']['peak_bytes']/2**30:.2f} | "
-            f"{r['cost']['flops_global']:.2e} | "
-            f"{r['collective_bytes_per_device']/2**30:.1f} | "
-            f"{r['params']/1e9:.1f}B |"
-        )
-    return "\n".join(lines)
-
-
-def roofline_table(recs) -> str:
-    lines = [
-        "| arch | shape | compute_s | memory_s | coll_s | bound | "
-        "MODEL/HLO FLOPs |",
-        "|---|---|---|---|---|---|---|",
-    ]
-    for r in recs:
-        if r["status"] != "ok" or r["mesh"] != "16x16":
-            continue
-        t = roofline_terms(r)
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {t['compute_s']:.4f} | "
-            f"{t['memory_s']:.4f} | {t['collective_s']:.4f} | "
-            f"{t['dominant']} | {t['useful_ratio']:.1%} |"
-        )
-    return "\n".join(lines)
 
 
 def load_history(path: str = HISTORY_PATH) -> list[dict]:
@@ -146,17 +103,7 @@ def gate_regression(
     return drop <= max_regress, msg
 
 
-def main(path=DEFAULT_JSON, history_path=HISTORY_PATH):
-    if os.path.exists(path):
-        recs = sorted(
-            load_records(path), key=lambda r: (r["arch"], r["shape"], r["mesh"])
-        )
-        print("### §Dry-run records\n")
-        print(dryrun_table(recs))
-        print("\n### §Roofline (single-pod 16×16)\n")
-        print(roofline_table(recs))
-    else:
-        print(f"(no dry-run records at {path})")
+def main(history_path=HISTORY_PATH):
     history = load_history(history_path)
     if history:
         print("\n### §Sim-lattice trajectory (BENCH_history.jsonl)\n")
@@ -170,7 +117,6 @@ if __name__ == "__main__":
     import sys
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--json", default=DEFAULT_JSON)
     ap.add_argument("--history", default=HISTORY_PATH)
     ap.add_argument(
         "--gate", action="store_true",
@@ -190,4 +136,4 @@ if __name__ == "__main__":
         )
         print(msg)
         sys.exit(0 if ok else 1)
-    main(args.json, args.history)
+    main(args.history)

@@ -1,234 +1,90 @@
-"""Roofline analysis (deliverable g): three-term model per (arch × shape),
-derived from the dry-run's compiled artifacts, PLUS the sim's real hot path —
-an ``aircomp`` row for the fused Eq. 5→8 aggregation kernel derived from the
-lattice executable's own XLA ``cost_analysis``/``memory_analysis``.
+"""Roofline of the sim's hot path: the fused Eq. 5→8 ``aircomp`` kernel,
+read off the lattice executable's own XLA ``cost_analysis``.
 
-    compute    = HLO_FLOPs / (chips · peak_FLOP/s)
-    memory     = HLO_bytes / (chips · HBM_bw)
-    collective = collective_bytes_per_device / link_bw
+    compute = HLO_FLOPs / peak_FLOP/s
+    memory  = HLO_bytes / HBM_bw
 
-Hardware constants default to TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link — and are overridable per run via ``--peak-flops``,
-``--hbm-bw``, ``--link-bw`` (values in FLOP/s and bytes/s) or the
-``REPRO_ROOFLINE_PEAK_FLOPS`` / ``REPRO_ROOFLINE_HBM_BW`` /
-``REPRO_ROOFLINE_LINK_BW`` environment variables (CLI wins over env wins
-over the defaults).
-
-Reads the JSONL emitted by ``python -m repro.launch.dryrun --json <path>``;
-with no records available it prints instructions instead of fabricating
-numbers. The aircomp row needs no dry run: it compiles a small sim lattice
-in-process (fused backend, interpret mode on CPU) and reads the flops/bytes
-XLA reports for that program — the fused kernel is VPU-bound, so its
-roofline term is the HBM-bytes one (see kernels/aircomp/kernel.py).
+The peaks come from :data:`PEAKS`, keyed by the ``device_kind`` JAX reports
+for the device the lattice compiled for. A device that is not in the table
+is an error, never a default: a CPU run has no roofline.
 """
 from __future__ import annotations
 
-import argparse
-import json
-import os
-
-PEAK_FLOPS = 197e12        # bf16 per chip (default; see hw_constants)
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # bytes/s per ICI link
-
-DEFAULT_JSON = os.path.join(os.path.dirname(__file__), "dryrun_results.jsonl")
-
-# MODEL_FLOPS token counts: 6·N·D training, 2·N·D inference fwd (per step).
-# Unknown shapes fall back to model_flops=0 / useful_ratio=0 instead of
-# KeyError — the compute/memory/collective terms don't need the token count.
-_SHAPE_TOKENS = {
-    "train_4k": 256 * 4096, "prefill_32k": 32 * 32768,
-    "decode_32k": 128, "long_500k": 1,
+# device_kind -> published per-chip peaks. Source: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 16 GB HBM.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "hbm_bytes": 16e9},
 }
 
 
-def hw_constants(
-    peak_flops: float | None = None,
-    hbm_bw: float | None = None,
-    link_bw: float | None = None,
-) -> tuple[float, float, float]:
-    """Resolve (PEAK_FLOPS, HBM_BW, LINK_BW): explicit arg > REPRO_ROOFLINE_*
-    env > the module-level TPU-v5e defaults."""
-
-    def pick(arg, env_name, default):
-        if arg is not None:
-            return float(arg)
-        env = os.environ.get(env_name)
-        return float(env) if env else default
-
-    return (
-        pick(peak_flops, "REPRO_ROOFLINE_PEAK_FLOPS", PEAK_FLOPS),
-        pick(hbm_bw, "REPRO_ROOFLINE_HBM_BW", HBM_BW),
-        pick(link_bw, "REPRO_ROOFLINE_LINK_BW", LINK_BW),
-    )
+def device_peaks(device_kind: str) -> dict:
+    """The :data:`PEAKS` row for ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 
-def load_records(path: str = DEFAULT_JSON) -> list[dict]:
-    if not os.path.exists(path):
-        return []
-    recs = {}
-    with open(path) as f:
-        for line in f:
-            r = json.loads(line)
-            # keep the LAST record per (arch, shape, mesh) — reruns supersede
-            recs[(r.get("arch"), r.get("shape"), r.get("mesh"))] = r
-    return list(recs.values())
-
-
-def roofline_terms(rec: dict, hw: tuple[float, float, float] | None = None) -> dict:
-    peak_flops, hbm_bw, link_bw = hw or hw_constants()
-    n = rec["n_devices"]
-    flops_global = rec["cost"]["flops_global"]
-    # whole-program bytes from the unrolled lowering (loop-faithful);
-    # divided by chips for the per-device HBM term
-    bytes_dev = rec["cost"]["bytes_accessed_global"] / n
-    coll_dev = rec["collective_bytes_per_device"]
-    compute_s = flops_global / (n * peak_flops)
-    memory_s = bytes_dev / hbm_bw
-    coll_s = coll_dev / link_bw
-    dominant = max(
-        ("compute", compute_s), ("memory", memory_s), ("collective", coll_s),
-        key=lambda kv: kv[1],
-    )[0]
-    shape_tokens = _SHAPE_TOKENS.get(rec["shape"])
-    if shape_tokens is None:
-        model_flops = 0.0  # unknown shape: no useful-FLOPs model, terms still valid
-    else:
-        mult = 6 if rec["shape"] == "train_4k" else 2
-        model_flops = mult * rec["active_params"] * shape_tokens
-    return {
-        "compute_s": compute_s,
-        "memory_s": memory_s,
-        "collective_s": coll_s,
-        "dominant": dominant,
-        "model_flops": model_flops,
-        "useful_ratio": model_flops / flops_global if flops_global > 0 else 0.0,
-    }
-
-
-def aircomp_roofline(
-    hw: tuple[float, float, float] | None = None,
-    mesh=None,
-) -> dict | None:
+def aircomp_roofline(mesh=None) -> dict:
     """Roofline terms for the sim's REAL hot path: compile a small fused
-    (``pallas_fused``, interpret on CPU) lattice sweep and read XLA's
-    ``cost_analysis``/``memory_analysis`` off the engine's AOT executable
-    (``sim.engine.lattice_cost_analysis``/``lattice_memory_analysis``).
+    (``pallas_fused``) lattice sweep and read XLA's
+    ``cost_analysis``/``memory_analysis`` off its AOT executable
+    (``repro.sim.latest_lattice_executable``).
 
     The fused aircomp kernel is one HBM pass over the (cells, N, D) gradient
-    block with no MXU work, so its binding term is ``memory_s`` — the row
-    this returns is expected (and asserted nowhere, printed honestly) to be
-    HBM-bound. Returns None if the sweep fails (e.g. jax broken).
+    block with no MXU work, so its binding term is expected to be
+    ``memory_s``; the row reports whichever bound is larger.
     """
-    peak_flops, hbm_bw, _ = hw or hw_constants()
-    os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
-    from benchmarks.common import bench_task, run_policies
-    from repro.sim.engine import _ENGINE_CACHE
+    import jax
 
+    from benchmarks.common import bench_task, run_policies
+    from repro.sim import latest_lattice_executable
+
+    kind = jax.devices()[0].device_kind
+    peaks = device_peaks(kind)
     task = bench_task()
     run_policies(
         task, policies=("pofl",), n_rounds=5, n_trials=2,
         backend="pallas_fused", mesh=mesh,
     )
-    eng = next(
-        (e for e in reversed(_ENGINE_CACHE.values()) if e._lattice_executables),
-        None,
-    )
-    if eng is None:
-        return None
-    cost = eng.lattice_cost_analysis()
-    mem = eng.lattice_memory_analysis()
+    exe = latest_lattice_executable()
+    cost = exe.cost_analysis()
+    mem = exe.memory_analysis()
     flops = float(cost.get("flops", 0.0))
     bytes_acc = float(cost.get("bytes accessed", 0.0))
-    compute_s = flops / peak_flops
-    memory_s = bytes_acc / hbm_bw
-    hbm_dev = 0
-    if mem is not None:
-        hbm_dev = (
-            int(getattr(mem, "argument_size_in_bytes", 0))
-            + int(getattr(mem, "output_size_in_bytes", 0))
-            + int(getattr(mem, "temp_size_in_bytes", 0))
-        )
+    compute_s = flops / peaks["flops"]
+    memory_s = bytes_acc / peaks["hbm_bw"]
     return {
+        "device_kind": kind,
         "flops": flops,
         "bytes_accessed": bytes_acc,
         "compute_s": compute_s,
         "memory_s": memory_s,
         "dominant": "memory" if memory_s >= compute_s else "compute",
-        "per_device_hbm_bytes": hbm_dev,
+        "per_device_hbm_bytes": (
+            mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes
+        ),
     }
 
 
-def main(
-    path: str = DEFAULT_JSON,
-    peak_flops: float | None = None,
-    hbm_bw: float | None = None,
-    link_bw: float | None = None,
-):
-    hw = hw_constants(peak_flops, hbm_bw, link_bw)
-    rows = []
-
-    air = None
-    try:
-        air = aircomp_roofline(hw)
-    except Exception as e:  # noqa: BLE001 - the dry-run rows must still print
-        print(f"[roofline] aircomp lattice row unavailable: {type(e).__name__}: {e}")
-    if air is not None:
-        print("\n== Roofline: sim hot path (fused aircomp lattice) ==")
-        print(
-            f"{'kernel':>22s} {'compute_s':>12s} {'memory_s':>12s} "
-            f"{'bound':>8s} {'MiB/dev':>8s}"
-        )
-        print(
-            f"{'aircomp_fused':>22s} {air['compute_s']:12.3e} "
-            f"{air['memory_s']:12.3e} {air['dominant']:>8s} "
-            f"{air['per_device_hbm_bytes']/2**20:8.2f}"
-        )
-        rows.append(({"arch": "sim", "shape": "aircomp", "mesh": "-"}, air))
-
-    recs = [r for r in load_records(path) if r.get("status") == "ok"]
-    if not recs:
-        print(
-            "[roofline] no dry-run records found at", path,
-            "\n  run: PYTHONPATH=src python -m repro.launch.dryrun"
-            " --arch all --shape all --json", path,
-        )
-        return rows
-    recs.sort(key=lambda r: (r["arch"], r["shape"], r["mesh"]))
-    print(f"\n== Roofline (from {len(recs)} dry-run records) ==")
+def main() -> dict:
+    air = aircomp_roofline()
+    print(f"\n== Roofline: sim hot path (fused aircomp lattice) on {air['device_kind']} ==")
     print(
-        f"{'arch':>22s} {'shape':<12s} {'mesh':>8s} "
-        f"{'compute_s':>10s} {'memory_s':>10s} {'coll_s':>10s} "
-        f"{'bound':>10s} {'useful':>7s} {'GiB/dev':>8s}"
+        f"{'kernel':>22s} {'compute_s':>12s} {'memory_s':>12s} "
+        f"{'bound':>8s} {'MiB/dev':>8s}"
     )
-    for r in recs:
-        t = roofline_terms(r, hw)
-        rows.append((r, t))
-        print(
-            f"{r['arch']:>22s} {r['shape']:<12s} {r['mesh']:>8s} "
-            f"{t['compute_s']:10.4f} {t['memory_s']:10.4f} "
-            f"{t['collective_s']:10.4f} {t['dominant']:>10s} "
-            f"{t['useful_ratio']:7.2%} "
-            f"{r['memory']['peak_bytes']/2**30:8.2f}"
-        )
-    return rows
+    print(
+        f"{'aircomp_fused':>22s} {air['compute_s']:12.3e} "
+        f"{air['memory_s']:12.3e} {air['dominant']:>8s} "
+        f"{air['per_device_hbm_bytes']/2**20:8.2f}"
+    )
+    return air
 
 
 if __name__ == "__main__":
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--json", default=DEFAULT_JSON)
-    ap.add_argument(
-        "--peak-flops", type=float, default=None,
-        help="peak FLOP/s per chip (default: TPU v5e 197e12; env "
-        "REPRO_ROOFLINE_PEAK_FLOPS)",
-    )
-    ap.add_argument(
-        "--hbm-bw", type=float, default=None,
-        help="HBM bytes/s per chip (default 819e9; env REPRO_ROOFLINE_HBM_BW)",
-    )
-    ap.add_argument(
-        "--link-bw", type=float, default=None,
-        help="ICI bytes/s per link (default 50e9; env REPRO_ROOFLINE_LINK_BW)",
-    )
-    a = ap.parse_args()
-    main(a.json, a.peak_flops, a.hbm_bw, a.link_bw)
+    main()

@@ -85,10 +85,16 @@ overwrite (``python -m benchmarks.report`` renders it).
                                      come from worker 0, where the lattice
                                      engines live)
 
-Set ``REPRO_COMPILE_CACHE=<dir>`` to persist XLA compiles across runs
-(``repro.sim.compile_cache``): a repeat cold run then reloads every lattice
-program from disk instead of recompiling (compile_seconds collapses to the
+XLA compiles persist across runs (``repro.sim.compile_cache``) in
+``$JAX_COMPILATION_CACHE_DIR`` when set, else in the checkout's fixed
+``.jax_cache``: a repeat cold run then reloads every lattice program from
+disk instead of recompiling (compile_seconds collapses to the
 deserialization cost).
+
+The process exits non-zero when any phase fails; the failing phase's CSV
+line carries ``ERROR:<type>:<message>``. ``--hosts H > 1`` spawns CPU
+workers through ``repro.launch.distributed`` and so needs
+``JAX_PLATFORMS=cpu``.
 
 ``--backend {jnp,pallas_fused}`` selects the aggregation backend and
 ``--mesh N`` shards the lattice's cell axis over the first N local devices
@@ -119,7 +125,9 @@ import datetime
 import json
 import os
 import subprocess
+import sys
 import time
+import traceback
 
 _REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 HISTORY_PATH = os.path.join(_REPO_ROOT, "BENCH_history.jsonl")
@@ -159,13 +167,18 @@ def _csv(name: str, seconds: float, derived: str):
     print(f"CSV,{name},{seconds*1e6:.0f},{derived}", flush=True)
 
 
-def _run(name: str, fn, derive):
+def _run(name: str, fn, derive) -> bool:
+    """Run one phase and print its CSV line; False when it raised (the
+    remaining phases still run, and ``main`` exits non-zero at the end)."""
     t0 = time.time()
     try:
         out = fn()
         _csv(name, time.time() - t0, derive(out))
-    except Exception as e:  # noqa: BLE001
+        return True
+    except Exception as e:  # noqa: BLE001 - the boundary that reports a phase
+        traceback.print_exc()
         _csv(name, time.time() - t0, f"ERROR:{type(e).__name__}:{e}")
+        return False
 
 
 def _kernel_micro():
@@ -382,11 +395,11 @@ def _bench_sim(
 
 def main(argv: list[str] | None = None) -> None:
     from repro.core import BACKENDS
-    from repro.sim import enable_compile_cache
+    from repro.sim.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
 
-    # REPRO_COMPILE_CACHE=<dir> persists every XLA compile below across runs
-    # (no-op when unset); must precede the first compile to catch them all
-    enable_compile_cache()
+    # persist every XLA compile below across runs; must precede the first
+    # compile to catch them all
+    enable_compile_cache(CHECKOUT_CACHE_DIR)
 
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
@@ -452,6 +465,11 @@ def main(argv: list[str] | None = None) -> None:
     # every other benchmark silently proceeds without BENCH_sim.json
     if args.hosts < 1:
         parser.error(f"--hosts must be >= 1 (got {args.hosts})")
+    if args.hosts > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        parser.error(
+            "--hosts > 1 spawns CPU workers (repro.launch.distributed); "
+            "run it with JAX_PLATFORMS=cpu"
+        )
     # validate the algorithm axis UP FRONT too: a malformed --algorithms is a
     # hard parser error (exit 2), never a mid-run CSV ERROR line
     from repro.core.local_update import ALGORITHMS
@@ -512,19 +530,10 @@ def main(argv: list[str] | None = None) -> None:
             f"--mesh {args.mesh} must divide evenly across --hosts {args.hosts}"
         )
 
-    from benchmarks import (
-        fig3_single_device,
-        fig4_multi_device,
-        fig5_noise_power,
-        fig6_num_devices,
-        fig7_heterogeneity,
-        roofline,
-        table1_alpha,
-    )
-
+    ok = []
     if not args.sim_only:
-        _run("kernels_microbench", _kernel_micro, lambda d: d)
-    _run(
+        ok.append(_run("kernels_microbench", _kernel_micro, lambda d: d))
+    ok.append(_run(
         "sim_lattice",
         lambda: _bench_sim(
             backend=args.backend, mesh_devices=mesh_total,
@@ -542,53 +551,58 @@ def main(argv: list[str] | None = None) -> None:
                 d["per_device_hbm_bytes"], d["dim"], d["n_hosts"],
             )
         ),
+    ))
+    if not args.sim_only:
+        ok += _paper_phases()
+    if not all(ok):
+        sys.exit(1)
+
+
+def _paper_phases() -> list[bool]:
+    """The figure/table reproductions and the roofline, one phase each."""
+    from benchmarks import (
+        fig3_single_device,
+        fig4_multi_device,
+        fig5_noise_power,
+        fig6_num_devices,
+        fig7_heterogeneity,
+        roofline,
+        table1_alpha,
     )
-    if args.sim_only:
-        return
-    _run(
-        "fig3_single_device", fig3_single_device.main,
-        lambda r: "pofl=%.3f noisefree=%.3f chan=%.3f" % (
-            r["mnist"]["pofl"]["best_acc"],
-            r["mnist"]["noisefree"]["best_acc"],
-            r["mnist"]["channel"]["best_acc"],
-        ),
-    )
-    _run(
-        "fig4_multi_device", fig4_multi_device.main,
-        lambda r: "pofl=%.3f det=%.3f" % (
-            r["mnist"]["pofl"]["best_acc"],
-            r["mnist"]["deterministic"]["best_acc"],
-        ),
-    )
-    _run(
-        "fig5_noise_power", fig5_noise_power.main,
-        lambda r: "pofl@1e-9=%.3f chan@1e-9=%.3f" % (
-            r[1e-9]["pofl"]["best_acc"], r[1e-9]["channel"]["best_acc"],
-        ),
-    )
-    _run(
-        "fig6_num_devices", fig6_num_devices.main,
-        lambda r: "pofl@S1=%.3f pofl@S10=%.3f pofl@S30=%.3f" % (
-            r[1]["pofl"]["best_acc"], r[10]["pofl"]["best_acc"],
-            r[30]["pofl"]["best_acc"],
-        ),
-    )
-    _run(
-        "fig7_heterogeneity", fig7_heterogeneity.main,
-        lambda r: "pofl@C1=%.3f pofl@C8=%.3f" % (
-            r[1]["pofl"]["best_acc"], r[8]["pofl"]["best_acc"],
-        ),
-    )
-    _run(
-        "table1_alpha", table1_alpha.main,
-        lambda r: "; ".join(
-            f"s={k:.0e}:best_a={max(v, key=v.get)}" for k, v in r.items()
-        ),
-    )
-    _run(
-        "roofline", roofline.main,
-        lambda rows: f"{len(rows)} (arch,shape,mesh) records",
-    )
+
+    phases = [
+        ("fig3_single_device", fig3_single_device.main,
+         lambda r: "pofl=%.3f noisefree=%.3f chan=%.3f" % (
+             r["mnist"]["pofl"]["best_acc"],
+             r["mnist"]["noisefree"]["best_acc"],
+             r["mnist"]["channel"]["best_acc"],
+         )),
+        ("fig4_multi_device", fig4_multi_device.main,
+         lambda r: "pofl=%.3f det=%.3f" % (
+             r["mnist"]["pofl"]["best_acc"],
+             r["mnist"]["deterministic"]["best_acc"],
+         )),
+        ("fig5_noise_power", fig5_noise_power.main,
+         lambda r: "pofl@1e-9=%.3f chan@1e-9=%.3f" % (
+             r[1e-9]["pofl"]["best_acc"], r[1e-9]["channel"]["best_acc"],
+         )),
+        ("fig6_num_devices", fig6_num_devices.main,
+         lambda r: "pofl@S1=%.3f pofl@S10=%.3f pofl@S30=%.3f" % (
+             r[1]["pofl"]["best_acc"], r[10]["pofl"]["best_acc"],
+             r[30]["pofl"]["best_acc"],
+         )),
+        ("fig7_heterogeneity", fig7_heterogeneity.main,
+         lambda r: "pofl@C1=%.3f pofl@C8=%.3f" % (
+             r[1]["pofl"]["best_acc"], r[8]["pofl"]["best_acc"],
+         )),
+        ("table1_alpha", table1_alpha.main,
+         lambda r: "; ".join(
+             f"s={k:.0e}:best_a={max(v, key=v.get)}" for k, v in r.items()
+         )),
+        ("roofline", roofline.main,
+         lambda air: "aircomp %s-bound on %s" % (air["dominant"], air["device_kind"])),
+    ]
+    return [_run(name, fn, derive) for name, fn, derive in phases]
 
 
 if __name__ == "__main__":

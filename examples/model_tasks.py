@@ -25,6 +25,7 @@ import time
 import numpy as np
 
 from repro.core.pofl import POFLConfig
+from repro.sim.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
 from repro.sim import (
     FUSED_POLICY,
     LatticeSpec,
@@ -57,6 +58,7 @@ def main(argv=None):
         help="which battery to run (and which golden table to print)",
     )
     args = parser.parse_args(argv)
+    enable_compile_cache(CHECKOUT_CACHE_DIR)
     b = BATTERY[args.task]
 
     task = make_model_task(**b["task_kw"])

@@ -13,9 +13,11 @@ from repro.core.pofl import POFLConfig, run_pofl
 from repro.data.partition import partition_noniid_shards
 from repro.data.synthetic import make_classification_dataset
 from repro.models import small
+from repro.sim.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
 
 
 def main():
+    enable_compile_cache(CHECKOUT_CACHE_DIR)
     # 1. data: synthetic MNIST-like, non-IID 2-classes-per-device shards
     key = jax.random.PRNGKey(0)
     k_train, k_test, k_init = jax.random.split(key, 3)

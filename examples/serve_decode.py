@@ -16,6 +16,7 @@ from repro.launch.serve import Server
 from repro.models import api
 from repro.models.cache import pad_cache
 from repro.models.config import InputShape
+from repro.sim.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
 
 
 def main():
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache(CHECKOUT_CACHE_DIR)
 
     cfg = configs.reduced_config(args.arch)
     cfg = dataclasses.replace(cfg, n_layers=4)

@@ -4,8 +4,9 @@ Runs (3 policies × 2 noise powers × 4 trials) = 24 cells of PO-FL training
 through ``repro.sim`` — ONE policy-fused vmapped+scanned compile for the
 whole sweep (the policy axis is traced), metrics streamed out once — under
 temporally-correlated Gauss–Markov fading with random device dropout
-(scenarios the per-round ``run_pofl`` loop cannot express). Set
-``REPRO_COMPILE_CACHE=<dir>`` to persist that one compile across runs. ``--mesh N`` shards the 8-cell-per-policy axis over N devices
+(scenarios the per-round ``run_pofl`` loop cannot express). That one
+compile persists across runs in ``$JAX_COMPILATION_CACHE_DIR``, else in the
+checkout's ``.jax_cache``. ``--mesh N`` shards the 8-cell-per-policy axis over N devices
 (results are identical — only placement changes):
 
     PYTHONPATH=src python examples/sim_lattice.py [--backend pallas_fused]
@@ -46,6 +47,7 @@ from repro.sim import (
     make_partition,
     run_lattice,
 )
+from repro.sim.compile_cache import CHECKOUT_CACHE_DIR
 
 
 def main(argv=None):
@@ -85,9 +87,7 @@ def main(argv=None):
     args = parser.parse_args(argv)
     algorithms = tuple(s.strip() for s in args.algorithms.split(","))
 
-    # REPRO_COMPILE_CACHE=<dir> persists the lattice's XLA compile across
-    # runs (repro.sim.compile_cache); no-op when unset
-    cache_dir = enable_compile_cache()
+    cache_dir = enable_compile_cache(CHECKOUT_CACHE_DIR)
 
     if args.distributed:
         # must precede the first device query; a missing env contract just

@@ -20,6 +20,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.launch.train import POFLTrainer, TrainerConfig, run_training
 from repro.models.config import InputShape
 from repro.optim.optimizers import adamw, cosine_schedule
+from repro.sim.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
 
 
 def main():
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--arch", default="qwen2-0.5b",
                     help="architecture family to scale down")
     args = ap.parse_args()
+    enable_compile_cache(CHECKOUT_CACHE_DIR)
 
     cfg = configs.base_config(args.arch)
     cfg = dataclasses.replace(

@@ -25,23 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """Version-compat shim: ``jax.shard_map`` (new, ``check_vma``) falls back
-    to ``jax.experimental.shard_map.shard_map`` (old, ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as sm_old
-
-    return sm_old(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def aircomp_allreduce(
     local_grads,
     coeff: jnp.ndarray,
@@ -84,11 +67,12 @@ def make_sharded_aggregator(mesh, axis_name: str = "data"):
         )
         return y[None, :]
 
-    wrapped = _shard_map(
+    wrapped = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(P(axis_name, None), P(axis_name), P(), P()),
         out_specs=P(axis_name, None),
+        check_vma=False,
     )
 
     def agg(g, coeffs, noise_amp, key):

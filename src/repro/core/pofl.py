@@ -28,8 +28,9 @@ computation. The transmit/aggregate stage is parameterized by an
   * ``jnp``           — the exact reference path (Eq. 16 / full Eq. 5→8,
     per ``cfg.simulate_physical``); the default, bit-identical to the seed.
   * ``pallas_fused``  — the one-HBM-pass fused Eq. 5→8 kernel
-    (``kernels/aircomp``): the Pallas TPU kernel on TPU, its pure-jnp oracle
-    on CPU, interpret mode via ``REPRO_PALLAS_INTERPRET=1`` (parity path).
+    (``kernels/aircomp``): the compiled Pallas kernel on TPU, its pure-jnp
+    oracle elsewhere, interpret mode off the TPU via
+    ``REPRO_PALLAS_INTERPRET=1`` (parity path).
     Semantics are the *physical* chain (algebraically equal to
     ``simulate_physical=True``; differs from Eq. 16 by ``(1−Σρ)·M_g``).
 
@@ -49,7 +50,6 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
@@ -167,10 +167,10 @@ def _model_sharded_local_stats(
         norm = jnp.sqrt(jax.lax.psum(jnp.sum(gv * gv, axis=-1), ax))
         return mean, var, norm
 
-    mean, var, norm = shard_map(
+    mean, var, norm = jax.shard_map(
         stats_block, mesh=ms.mesh,
         in_specs=(P(None, ax),), out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(g_pad)
     return aircomp.GradStats(mean=mean, var=var, norm=norm)
 
@@ -213,12 +213,12 @@ def _model_sharded_combine(
             )
 
     ax = ms.axis
-    return shard_map(
+    return jax.shard_map(
         agg_block, mesh=ms.mesh,
         in_specs=(
             P(None, ax), P(ax), P(None), P(None), P(None), P(), P(), P(),
         ),
-        out_specs=P(ax), check_rep=False,
+        out_specs=P(ax), check_vma=False,
     )(g_pad, z_pad, rho, h, mask, m_g, v_g, a)
 
 
@@ -433,8 +433,8 @@ def aggregation_stage(
     Eq. 5 normalize → Lemma-1 transmit scale → Eq. 7 superpose → Eq. 8
     denoise/denormalize into one pass over the gradient matrix
     (``kernels/aircomp``). Under the lattice's cell vmap the fused
-    ``pallas_call`` batches into the trial-batched grid — the
-    ``aircomp_fused_batch`` layout — without host-side dispatch.
+    ``pallas_call`` batches into the trial-batched grid — exactly the
+    ``aircomp_fused_batch`` program — without host-side dispatch.
 
     ``model_shard`` switches to the D-sharded route: ``g`` is then the
     padded block (``ModelShard.pad_features``), ``stats`` the psum'd

@@ -17,7 +17,8 @@ TPU layout notes:
   * TILE_D is a multiple of 128 (lane dimension).
   * n_devices (≤ a few hundred in FL) sits in the sublane dimension; the
     device reduction is a VPU cross-sublane sum.
-  * scalars (M_g, V_g, a, W) ride in SMEM.
+  * scalars (M_g, V_g, a, W) ride in SMEM as one (1, 4) block, which
+    stays a legal block shape when the lattice vmaps the call.
 """
 from __future__ import annotations
 
@@ -48,10 +49,10 @@ def _clamp_tile(d: int, tile_d: int) -> int:
 
 
 def _aircomp_kernel(scalars_ref, coeff_ref, g_ref, z_ref, out_ref):
-    m_g = scalars_ref[0]
-    v_g = scalars_ref[1]
-    a = scalars_ref[2]
-    w = scalars_ref[3]  # Σ_i coeff_i
+    m_g = scalars_ref[0, 0]
+    v_g = scalars_ref[0, 1]
+    a = scalars_ref[0, 2]
+    w = scalars_ref[0, 3]  # Σ_i coeff_i
 
     g = g_ref[...].astype(jnp.float32)          # (N, T)
     z = z_ref[...].astype(jnp.float32)          # (1, T)
@@ -60,72 +61,6 @@ def _aircomp_kernel(scalars_ref, coeff_ref, g_ref, z_ref, out_ref):
     sqrt_vg = jax.lax.sqrt(eps_guard(v_g))
     acc = jnp.sum(coeff * g, axis=0, keepdims=True)  # (1, T)
     out_ref[...] = (acc - w * m_g + (sqrt_vg / a) * z + m_g).astype(out_ref.dtype)
-
-
-def _aircomp_batch_kernel(scalars_ref, coeff_ref, g_ref, z_ref, out_ref):
-    b = pl.program_id(0)
-    m_g = scalars_ref[b, 0]
-    v_g = scalars_ref[b, 1]
-    a = scalars_ref[b, 2]
-    w = scalars_ref[b, 3]  # Σ_i coeff_i for this trial
-
-    g = g_ref[0].astype(jnp.float32)            # (N, T)
-    z = z_ref[0].astype(jnp.float32)            # (1, T)
-    coeff = coeff_ref[0].astype(jnp.float32)    # (N, 1)
-
-    sqrt_vg = jax.lax.sqrt(eps_guard(v_g))
-    acc = jnp.sum(coeff * g, axis=0, keepdims=True)  # (1, T)
-    out_ref[0] = (acc - w * m_g + (sqrt_vg / a) * z + m_g).astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_d", "interpret"))
-def aircomp_fused_batch(
-    g: jnp.ndarray,       # (n_trials, n_devices, D)
-    coeff: jnp.ndarray,   # (n_trials, n_devices)  mask_i · ρ_i per trial
-    m_g: jnp.ndarray,     # (n_trials,)
-    v_g: jnp.ndarray,     # (n_trials,)
-    a: jnp.ndarray,       # (n_trials,)
-    z: jnp.ndarray,       # (n_trials, D)
-    *,
-    tile_d: int = DEFAULT_TILE_D,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Trial-batched fused Eq. 5→8 aggregation — one kernel launch serves a
-    whole lattice batch (e.g. every cell of a ``repro.sim`` lattice sharing a
-    policy). Returns ŷ of shape (n_trials, D).
-
-    Grid is (n_trials, D/tile_d): the trial axis rides the outer grid
-    dimension so each (N, TILE_D) gradient block is loaded from HBM exactly
-    once, same as the single-trial kernel; per-trial scalars sit in SMEM and
-    are indexed by the grid position.
-    """
-    bt, n, d = g.shape
-    tile_d = _clamp_tile(d, tile_d)
-    d_pad = ((d + tile_d - 1) // tile_d) * tile_d
-    if d_pad != d:
-        g = jnp.pad(g, ((0, 0), (0, 0), (0, d_pad - d)))
-        z = jnp.pad(z, ((0, 0), (0, d_pad - d)))
-
-    scalars = jnp.stack(
-        [m_g.astype(jnp.float32), v_g.astype(jnp.float32),
-         a.astype(jnp.float32), jnp.sum(coeff, axis=-1).astype(jnp.float32)],
-        axis=-1,
-    )  # (n_trials, 4)
-
-    out = pl.pallas_call(
-        _aircomp_batch_kernel,
-        grid=(bt, d_pad // tile_d),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),              # scalars (B, 4)
-            pl.BlockSpec((1, n, 1), lambda b, i: (b, 0, 0)),    # coeff column
-            pl.BlockSpec((1, n, tile_d), lambda b, i: (b, 0, i)),  # grad tile
-            pl.BlockSpec((1, 1, tile_d), lambda b, i: (b, 0, i)),  # noise tile
-        ],
-        out_specs=pl.BlockSpec((1, 1, tile_d), lambda b, i: (b, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((bt, 1, d_pad), g.dtype),
-        interpret=interpret,
-    )(scalars, coeff[:, :, None], g, z[:, None, :])
-    return out[:, 0, :d]
 
 
 @functools.partial(jax.jit, static_argnames=("tile_d", "interpret"))
@@ -146,6 +81,13 @@ def aircomp_fused(
     than (128-lane-aligned) D is clamped first, so shard-local blocks of a
     model-sharded lattice launch a snug grid rather than padding to the
     default tile.
+
+    Batching: under ``jax.vmap`` (the lattice's cell axis) Pallas prepends
+    a grid dimension and a squeezed leading block dimension to every
+    operand. Each block therefore keeps its last two dimensions equal to
+    the array's own — the scalars ride as a ``(1, 4)`` SMEM block, not a
+    whole ``(4,)`` vector, whose batched ``(cells, 4)`` form the TPU
+    lowering refuses.
     """
     n, d = g.shape
     tile_d = _clamp_tile(d, tile_d)
@@ -157,13 +99,13 @@ def aircomp_fused(
     scalars = jnp.stack(
         [m_g.astype(jnp.float32), v_g.astype(jnp.float32),
          a.astype(jnp.float32), jnp.sum(coeff).astype(jnp.float32)]
-    )
+    )[None, :]
 
     out = pl.pallas_call(
         _aircomp_kernel,
         grid=(d_pad // tile_d,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),        # scalars (4,)
+            pl.BlockSpec((1, 4), lambda i: (0, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((n, 1), lambda i: (0, 0)),       # coeff column
             pl.BlockSpec((n, tile_d), lambda i: (0, i)),  # gradient tile
             pl.BlockSpec((1, tile_d), lambda i: (0, i)),  # noise tile
@@ -173,3 +115,27 @@ def aircomp_fused(
         interpret=interpret,
     )(scalars, coeff[:, None], g, z[None, :])
     return out[0, :d]
+
+
+@functools.partial(jax.jit, static_argnames=("tile_d", "interpret"))
+def aircomp_fused_batch(
+    g: jnp.ndarray,       # (n_trials, n_devices, D)
+    coeff: jnp.ndarray,   # (n_trials, n_devices)  mask_i · ρ_i per trial
+    m_g: jnp.ndarray,     # (n_trials,)
+    v_g: jnp.ndarray,     # (n_trials,)
+    a: jnp.ndarray,       # (n_trials,)
+    z: jnp.ndarray,       # (n_trials, D)
+    *,
+    tile_d: int = DEFAULT_TILE_D,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Trial-batched fused Eq. 5→8 aggregation — one kernel launch serves a
+    whole lattice batch. Returns ŷ of shape (n_trials, D).
+
+    The ``vmap`` of :func:`aircomp_fused`: the grid becomes
+    (n_trials, D/tile_d), so each (N, TILE_D) gradient block is loaded from
+    HBM exactly once and each trial's scalars sit in SMEM — the same
+    program the lattice's cell ``vmap`` builds around the single-round op.
+    """
+    one = functools.partial(aircomp_fused, tile_d=tile_d, interpret=interpret)
+    return jax.vmap(one)(g, coeff, m_g, v_g, a, z)

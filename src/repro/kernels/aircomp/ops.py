@@ -2,13 +2,14 @@
 
 ``use_pallas`` values:
 
-  * ``'auto'``      — the Pallas kernel on TPU, the pure-jnp reference on
-    CPU; setting the ``REPRO_PALLAS_INTERPRET=1`` env var forces interpret
-    mode instead (the CPU parity path for the engine's ``pallas_fused``
-    aggregation backend). The var is read at TRACE time: set it before
-    building engines/jits — already-compiled traces keep their mode
-    (``sim.engine.cached_engine`` keys on it, so cached engines are safe;
-    hand-built ``SimEngine``/lattice jits are not).
+  * ``'auto'``      — the compiled Pallas kernel on TPU, the pure-jnp
+    reference elsewhere; off the TPU, the ``REPRO_PALLAS_INTERPRET=1`` env
+    var selects interpret mode instead (the CPU parity path for the
+    engine's ``pallas_fused`` aggregation backend). The var is read at
+    TRACE time: set it before building engines/jits — already-compiled
+    traces keep their mode (``sim.engine.cached_engine`` keys on it, so
+    cached engines are safe; hand-built ``SimEngine``/lattice jits are
+    not). On a TPU it is ignored: the chip always runs the kernel compiled.
   * ``True``        — the Pallas kernel (compiled).
   * ``'interpret'`` — the Pallas kernel in interpret mode (runs anywhere;
     slow — tests/parity only).
@@ -40,9 +41,9 @@ def _on_tpu() -> bool:
 def _resolve(use_pallas: str | bool) -> str | bool:
     """Normalize a ``use_pallas`` argument to True / False / 'interpret'."""
     if use_pallas == "auto":
-        if os.environ.get("REPRO_PALLAS_INTERPRET"):
-            return "interpret"
-        return _on_tpu()
+        if _on_tpu():
+            return True
+        return "interpret" if os.environ.get("REPRO_PALLAS_INTERPRET") else False
     return use_pallas
 
 

@@ -38,9 +38,12 @@ Usage (CPU CI / laptop):
     python -m repro.launch.distributed --procs 2 --devices-per-proc 4 \\
         -- python examples/sim_lattice.py --distributed
 
-Workers force ``JAX_PLATFORMS=cpu``: this launcher exists for the
-fake-device CPU story; real accelerator pods bring their own process
-launcher (SLURM/GKE) and only need the env contract above.
+This launcher is a CPU rehearsal tool: it refuses to spawn unless the
+launching process itself runs with ``JAX_PLATFORMS=cpu``, and every worker
+runs on the CPU. A chip belongs to one process at a time, so on a machine
+with a chip these workers could only report CPU numbers as the machine's.
+Real accelerator pods bring their own process launcher (SLURM/GKE) and
+only need the env contract above.
 """
 from __future__ import annotations
 
@@ -90,6 +93,17 @@ class WorkerResult:
     output: str  # merged stdout+stderr
 
 
+def require_cpu_platform() -> None:
+    """Refuse to spawn workers unless this process runs with
+    ``JAX_PLATFORMS=cpu`` (see the module docstring)."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "repro.launch.distributed spawns CPU workers only; run it with "
+            "JAX_PLATFORMS=cpu (got JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r})"
+        )
+
+
 def worker_env(
     coordinator: str,
     num_processes: int,
@@ -101,7 +115,9 @@ def worker_env(
     a fresh fake-device pool (any inherited device-count flag is stripped —
     the child's pool must be exactly ``devices_per_proc``) and import roots
     matching the parent (``repro``'s src dir + the parent cwd, so workload
-    code resolves ``benchmarks``/``examples`` the way the parent would)."""
+    code resolves ``benchmarks``/``examples`` the way the parent would).
+    Raises unless this process runs with ``JAX_PLATFORMS=cpu``."""
+    require_cpu_platform()
     env = dict(os.environ if base_env is None else base_env)
     env[ENV_COORDINATOR] = coordinator
     env[ENV_NUM_PROCESSES] = str(num_processes)
@@ -553,7 +569,7 @@ def _worker_parity(args) -> None:
     from repro.sim.compile_cache import enable_compile_cache
     from repro.sim.multihost import initialize_distributed, make_global_cell_mesh
 
-    enable_compile_cache()  # REPRO_COMPILE_CACHE inherited from the launcher
+    enable_compile_cache()  # JAX_COMPILATION_CACHE_DIR inherited from the launcher
     initialize_distributed()
     import jax
 
@@ -579,7 +595,7 @@ def _worker_bench(args) -> None:
     from repro.sim.compile_cache import enable_compile_cache
     from repro.sim.multihost import initialize_distributed, make_global_cell_mesh
 
-    enable_compile_cache()  # REPRO_COMPILE_CACHE inherited from the launcher
+    enable_compile_cache()  # JAX_COMPILATION_CACHE_DIR inherited from the launcher
     initialize_distributed()
     import jax
 

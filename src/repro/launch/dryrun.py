@@ -243,12 +243,10 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, verbose: bool = True):
             "reason": "pure full-attention arch — no long_500k variant (DESIGN §4)",
         }
 
-    from repro.launch.mesh import activate_mesh
-
     cfg = configs.get_config(arch, shape)
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = build_step(cfg, shape, mesh)
         lowered = bundle.fn.lower(*bundle.arg_structs.values())
         t_lower = time.time() - t0

@@ -8,37 +8,26 @@ Functions, not module-level constants — importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS *before* the first jax
 device query).
 
-``activate_mesh`` is the version-compat shim for entering a mesh context:
-the canonical spelling has moved across jax releases (``jax.set_mesh`` →
-``jax.sharding.use_mesh`` → the ``Mesh`` object's own context manager), and
-naming one of the newer APIs on an older jax raises AttributeError at call
-time. Use the shim everywhere a mesh is activated.
+Every mesh is built with ``Auto`` axis types: the step builders place
+arrays with explicit ``NamedSharding`` objects and let XLA propagate the rest,
+which is the ``Auto`` contract. ``jax.make_mesh`` defaults to ``Explicit``
+axes, under which ``jax.set_mesh`` turns on sharding-in-types and ops such
+as gathers demand an ``out_sharding``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def activate_mesh(mesh):
-    """Return a context manager that makes ``mesh`` the ambient mesh.
-
-    Tries ``jax.set_mesh`` (newest), then ``jax.sharding.use_mesh``, then
-    falls back to the ``Mesh`` context-manager protocol (``with mesh:``),
-    which every supported jax version implements.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 2):
@@ -46,7 +35,7 @@ def make_host_mesh(model: int = 2):
     n = len(jax.devices())
     model = min(model, n)
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

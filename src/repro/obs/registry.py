@@ -13,9 +13,9 @@ Reset semantics — the part the old scattered counters never agreed on:
   * :func:`reset_metrics` with a ``prefix`` zeroes exactly that namespace
     (``reset_engine_cache`` resets ``engine_cache.``, nothing else);
   * :func:`reset_metrics` with no prefix zeroes everything — including the
-    persistent-compile-cache counters, so a CI warm-run guard
-    (``REPRO_COMPILE_CACHE_EXPECT_HITS``) should never share a process with
-    an unscoped full reset (tests use prefix resets).
+    process-lifetime persistent-compile-cache counters, so code that reads
+    them across a whole run should never share a process with an unscoped
+    full reset (tests use prefix resets).
 
 No jax imports; safe from anywhere.
 """

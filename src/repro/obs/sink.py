@@ -1,6 +1,6 @@
 """The per-process JSONL event sink behind every obs span/counter/gauge.
 
-One env contract, mirroring ``REPRO_COMPILE_CACHE``:
+One env contract:
 
     REPRO_OBS_DIR=<dir>      stream every obs event into <dir> as JSONL
     REPRO_OBS_PROFILE=1      additionally capture jax.profiler traces around

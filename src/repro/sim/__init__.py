@@ -41,6 +41,7 @@ from repro.sim.engine import (
     SimState,
     cached_engine,
     engine_cache_stats,
+    latest_lattice_executable,
     lattice_compile_stats,
     lattice_memory_stats,
     reset_engine_cache,
@@ -101,6 +102,7 @@ __all__ = [
     "enable_compile_cache",
     "engine_cache_stats",
     "initialize_distributed",
+    "latest_lattice_executable",
     "lattice_compile_stats",
     "lattice_memory_stats",
     "latest_checkpoint",

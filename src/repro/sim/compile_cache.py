@@ -1,34 +1,26 @@
-"""Durable XLA compiles: JAX's persistent compilation cache, wired from one
-env/arg contract.
+"""Durable XLA compiles: JAX's persistent compilation cache.
 
 The lattice's dominant cold-call cost is the XLA compile (BENCH_sim.json's
 ``compile_seconds``), and it is identical across processes for identical
-programs — so paying it once per *machine* (or once per CI cache key)
-instead of once per process is pure win. This module turns JAX's persistent
-compilation cache on from the ``REPRO_COMPILE_CACHE`` environment variable
-(or an explicit path):
+programs — so paying it once per *machine* instead of once per process is
+pure win. The directory comes from JAX's own variable:
 
-    REPRO_COMPILE_CACHE=~/.cache/repro-xla python -m benchmarks.run
-    REPRO_COMPILE_CACHE=.jax-cache python -m pytest tests/test_lattice_sharded.py
+    JAX_COMPILATION_CACHE_DIR=~/.cache/repro-xla python -m benchmarks.run
 
-Callers: ``benchmarks/run.py``, ``examples/sim_lattice.py``, the
-``repro.launch.distributed`` worker entrypoints (the env var is inherited by
-every spawned worker), and ``tests/conftest.py`` (so CI can warm-run suites
-against an ``actions/cache``'d directory). All of them call
-:func:`enable_compile_cache` unconditionally — it is a no-op returning None
-when the contract is unset.
+When it is set, JAX reads it itself and this module sets no other
+directory. When it is not, the entry scripts (``chip_smoke.py``,
+``benchmarks/run.py``, ``examples/*``) pass :data:`CHECKOUT_CACHE_DIR`, a
+fixed path inside the checkout — never a temporary, per-process or timed
+name, since a directory that moves never hits. Library code, the test
+session (``tests/conftest.py``) and the ``repro.launch.distributed``
+workers (which inherit the variable) call :func:`enable_compile_cache`
+with no default, so they cache only where the variable says.
 
 Hit accounting: :func:`enable_compile_cache` registers a
 ``jax.monitoring`` listener counting the ``/jax/compilation_cache/*``
 events, exposed by :func:`persistent_cache_counters` — within one process a
 program compiled earlier in the SAME process hits jax's in-memory caches
-first, so persistent hits are expected on *fresh* processes (the CI
-assertion runs pytest twice and requires hits > 0 on the second run).
-
-Config-flag compat: everything is applied via ``jax.config.update`` guarded
-for absent flags (jax 0.4.37 has all of them; older jaxes degrade to
-whichever subset exists). Must run before the first compile to catch it,
-but is safe (and still effective for later compiles) at any point.
+first, so persistent hits are expected on *fresh* processes.
 """
 from __future__ import annotations
 
@@ -36,14 +28,19 @@ import os
 from typing import Any
 
 import jax
+from jax import monitoring
 
 from repro.obs.registry import counter_add, metric_value
 
-ENV_CACHE_DIR = "REPRO_COMPILE_CACHE"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+# <checkout>/.jax_cache — listed in .gitignore
+CHECKOUT_CACHE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "..", ".jax_cache")
+)
 
 # hit/miss counts live in the obs registry under ``compile_cache.`` —
-# PROCESS-LIFETIME counters (the CI warm-run guard reads them at session
-# end), so nothing may reset that namespace mid-process
+# PROCESS-LIFETIME counters, so nothing may reset that namespace mid-process
 _LISTENER_INSTALLED = False
 
 
@@ -58,40 +55,29 @@ def _install_listener() -> None:
     global _LISTENER_INSTALLED
     if _LISTENER_INSTALLED:
         return
-    try:
-        from jax import monitoring  # public since jax 0.4.x
-    except ImportError:  # pragma: no cover - very old jax
-        from jax._src import monitoring
     monitoring.register_event_listener(_count_cache_events)
     _LISTENER_INSTALLED = True
 
 
-def enable_compile_cache(path: str | None = None) -> str | None:
+def enable_compile_cache(default_dir: str | None = None) -> str | None:
     """Enable the persistent compilation cache; returns the cache dir or None.
 
-    ``path`` defaults to ``$REPRO_COMPILE_CACHE``; when neither is set this
-    is a no-op (None). The directory is created, every-compile persistence is
-    forced (min-entry-size/min-compile-time floors dropped — the lattice's
-    many small sub-programs should all hit on the next process), and the
-    hit/miss listener is installed.
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise ``default_dir``
+    is used; with neither this is a no-op (None). The directory is created,
+    every-compile persistence is forced (min-entry-size/min-compile-time
+    floors dropped — the lattice's many small sub-programs should all hit on
+    the next process), and the hit/miss listener is installed.
     """
-    path = path or os.environ.get(ENV_CACHE_DIR) or None
+    path = os.environ.get(ENV_CACHE_DIR) or default_dir
     if not path:
         return None
     path = os.path.abspath(os.path.expanduser(path))
     os.makedirs(path, exist_ok=True)
-    _apply_config("jax_compilation_cache_dir", path)
-    _apply_config("jax_persistent_cache_min_entry_size_bytes", -1)
-    _apply_config("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     _install_listener()
     return path
-
-
-def _apply_config(name: str, value) -> None:
-    try:
-        jax.config.update(name, value)
-    except (AttributeError, ValueError):  # pragma: no cover - older jax
-        pass
 
 
 def persistent_cache_counters() -> dict:
@@ -106,9 +92,10 @@ def persistent_cache_counters() -> dict:
 
 
 def cache_dir_entries(path: str | None = None) -> int:
-    """Number of cache payload files in the (env-contract) cache directory —
-    0 for unset/missing. jax writes one ``*-cache`` payload (plus an
-    ``-atime`` sidecar under LRU budgeting) per compiled program."""
+    """Number of cache payload files in ``path`` (default: the directory
+    ``$JAX_COMPILATION_CACHE_DIR`` names) — 0 for unset/missing. jax writes
+    one ``*-cache`` payload (plus an ``-atime`` sidecar under LRU budgeting)
+    per compiled program."""
     path = path or os.environ.get(ENV_CACHE_DIR) or None
     if not path or not os.path.isdir(path):
         return 0

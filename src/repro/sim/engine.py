@@ -714,18 +714,10 @@ class SimEngine:
 
     def lattice_cost_analysis(self) -> dict:
         """XLA ``cost_analysis`` (flops/bytes) of the most recent lattice
-        executable, as a flat dict ({} before the first compile).
-
-        jax-version compat: newer jax returns the dict directly, 0.4.x wraps
-        it in a one-element list (same shim as ``launch.dryrun``).
-        """
+        executable, as a flat dict ({} before the first compile)."""
         if not self._lattice_executables:
             return {}
-        compiled = next(reversed(self._lattice_executables.values()))
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        return dict(cost)
+        return dict(next(reversed(self._lattice_executables.values())).cost_analysis())
 
     def lattice_memory_analysis(self):
         """XLA ``memory_analysis`` (argument/output/temp bytes) of the most
@@ -993,6 +985,19 @@ def lattice_memory_stats() -> dict:
             ),
         }
     return stats
+
+
+def latest_lattice_executable():
+    """The most recently used AOT lattice executable across the cached
+    engines (None before any compile). Its ``as_text()`` is the optimized
+    HLO — a compiled Pallas kernel shows there as a ``tpu_custom_call``,
+    the jnp reference and interpret mode leave none — and its
+    ``output_shardings`` say which devices hold the records."""
+    latest = None
+    for engine in _ENGINE_CACHE.values():
+        if engine._lattice_executables:
+            latest = next(reversed(engine._lattice_executables.values()))
+    return latest
 
 
 def lattice_compile_stats() -> dict:

@@ -100,33 +100,22 @@ def initialize_distributed(cfg: DistributedConfig | None = None) -> bool:
     On CPU the cross-process collective implementation defaults to ``none``,
     which raises "Multiprocess computations aren't implemented on the CPU
     backend" at dispatch — so we switch it to ``gloo`` (shipped in jaxlib)
-    before the backend exists. Guarded by ``getattr``-style try/except for
-    jax versions that predate the flag.
+    before the backend exists.
     """
     global _INITIALIZED
     cfg = cfg or distributed_env()
     if cfg is None or cfg.num_processes <= 1:
         return _INITIALIZED
     if not _INITIALIZED:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):  # pragma: no cover - old jax
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         # bound the barrier wait: a half-formed topology (a peer crashed
         # before joining) must die loudly, not hang the worker forever
-        try:
-            jax.distributed.initialize(
-                coordinator_address=cfg.coordinator,
-                num_processes=cfg.num_processes,
-                process_id=cfg.process_id,
-                initialization_timeout=120,
-            )
-        except TypeError:  # pragma: no cover - jax without the kwarg
-            jax.distributed.initialize(
-                coordinator_address=cfg.coordinator,
-                num_processes=cfg.num_processes,
-                process_id=cfg.process_id,
-            )
+        jax.distributed.initialize(
+            coordinator_address=cfg.coordinator,
+            num_processes=cfg.num_processes,
+            process_id=cfg.process_id,
+            initialization_timeout=120,
+        )
         _INITIALIZED = True
     return True
 

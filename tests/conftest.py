@@ -1,4 +1,4 @@
-"""Shared fixtures: engine-cache hygiene + opt-in persistent compile cache.
+"""Shared fixtures: engine-cache hygiene + the persistent compile cache.
 
 The cross-call engine cache (``repro.sim.engine``) is process-global, so a
 test asserting on ``engine_cache_stats()`` counters (or on which engine a
@@ -6,16 +6,13 @@ call returns) would otherwise depend on which tests ran before it. Every
 test starts from an empty cache with zeroed counters; caching behavior is
 still fully exercised *within* each test (that is what the cache tests do).
 
-Persistent compiles: when ``REPRO_COMPILE_CACHE=<dir>`` is exported, every
-XLA compile in the test session is persisted there / reloaded from there
-(``repro.sim.compile_cache``) — CI runs the compile-heavy suites against an
-``actions/cache``'d directory. ``REPRO_COMPILE_CACHE_EXPECT_HITS=1``
-additionally makes the session FAIL unless at least one compile was served
-from the persistent cache — the warm-second-run assertion of the CI jobs.
+Persistent compiles: when ``JAX_COMPILATION_CACHE_DIR=<dir>`` is exported,
+every XLA compile in the test session is persisted there / reloaded from
+there (``repro.sim.compile_cache``) — CI runs the compile-heavy suites
+against an ``actions/cache``'d directory. Unset, the session caches nothing
+on disk.
 """
 from __future__ import annotations
-
-import os
 
 import pytest
 
@@ -47,9 +44,9 @@ def _fresh_engine_cache():
     obs span/engine/lattice counters.
 
     PREFIX resets only: the ``compile_cache.`` registry namespace is
-    process-lifetime — the ``REPRO_COMPILE_CACHE_EXPECT_HITS`` session-end
-    guard below reads it across the whole run, so no per-test reset (or
-    unscoped ``reset_metrics()``) may touch it.
+    process-lifetime — the session-end report below reads it across the
+    whole run, so no per-test reset (or unscoped ``reset_metrics()``) may
+    touch it.
     """
     reset_engine_cache()  # clears engines + the engine_cache. namespace
     for prefix in ("span.", "engine.", "lattice.", "multihost."):
@@ -59,22 +56,13 @@ def _fresh_engine_cache():
 
 
 @pytest.fixture(scope="session", autouse=True)
-def _persistent_cache_hits_guard():
-    """With ``REPRO_COMPILE_CACHE_EXPECT_HITS`` set, a session that never
-    hit the persistent compilation cache is a FAILURE — CI's warm re-run
-    proves compiles actually survive across processes."""
+def _persistent_cache_report():
+    """Print the session's persistent-cache hit/miss counts when a cache
+    directory is in use."""
     yield
-    counters = persistent_cache_counters()
     if _CACHE_DIR:
+        counters = persistent_cache_counters()
         print(
             f"\npersistent compile cache {_CACHE_DIR}: "
             f"{counters['hits']} hit(s), {counters['misses']} miss(es)"
-        )
-    if os.environ.get("REPRO_COMPILE_CACHE_EXPECT_HITS"):
-        assert _CACHE_DIR, (
-            "REPRO_COMPILE_CACHE_EXPECT_HITS needs REPRO_COMPILE_CACHE set"
-        )
-        assert counters["hits"] > 0, (
-            "expected persistent compilation-cache hits on this warm run, "
-            f"got none (counters: {counters}, dir: {_CACHE_DIR})"
         )

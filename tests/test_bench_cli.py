@@ -51,12 +51,42 @@ def test_mesh_2d_syntax_guards(capsys):
 def test_mesh_within_local_devices_passes_guard(monkeypatch):
     """A satisfiable --mesh must NOT trip the guard (the guard may only fire
     on impossible topologies). The benchmarks themselves are stubbed out."""
-    monkeypatch.setattr(bench_run, "_run", lambda *a, **k: None)
+    monkeypatch.setattr(bench_run, "_run", lambda *a, **k: True)
     bench_run.main(["--mesh", str(len(jax.devices()))])  # no SystemExit
 
 
 def test_hosts_must_be_positive():
     assert _error_code(["--hosts", "0"]) == 2
+
+
+def test_multihost_needs_cpu_platform(capsys, monkeypatch):
+    """--hosts > 1 spawns CPU workers: without JAX_PLATFORMS=cpu it must
+    refuse before anything touches a device."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert _error_code(["--hosts", "2"]) == 2
+    assert "JAX_PLATFORMS=cpu" in capsys.readouterr().err
+
+
+def test_failed_phase_exits_nonzero(capsys, monkeypatch):
+    """A phase that raises still lets the others print, but the process
+    exits non-zero — a run with an ERROR line never reads as a pass."""
+
+    def broken(**kw):
+        raise RuntimeError("phase broke")
+
+    monkeypatch.setattr(bench_run, "_bench_sim", broken)
+    assert _error_code(["--sim-only"]) == 1
+    assert "ERROR:RuntimeError:phase broke" in capsys.readouterr().out
+
+
+def test_roofline_peaks_only_for_known_devices():
+    """The roofline's peaks come from its table; a device it does not list
+    (the CPU here) is an error, never a default."""
+    from benchmarks.roofline import device_peaks
+
+    assert device_peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks(jax.devices()[0].device_kind)
 
 
 def test_mesh_must_divide_across_hosts(capsys):
@@ -92,7 +122,7 @@ def test_algorithm_axis_is_single_host_only(capsys):
 def test_valid_algorithm_axis_passes_guard(monkeypatch):
     """A well-formed multi-algorithm sweep must NOT trip the guards (the
     benchmarks themselves are stubbed out)."""
-    monkeypatch.setattr(bench_run, "_run", lambda *a, **k: None)
+    monkeypatch.setattr(bench_run, "_run", lambda *a, **k: True)
     bench_run.main(["--algorithms", "fedavg,fedprox", "--local-steps", "2"])
 
 
@@ -105,7 +135,7 @@ def test_task_cli_guards(capsys, monkeypatch):
     assert _error_code(["--task", "cnn", "--hosts", "2"]) == 2
     assert "single-host" in capsys.readouterr().err
     assert _error_code(["--task", "mlp"]) == 2
-    monkeypatch.setattr(bench_run, "_run", lambda *a, **k: None)
+    monkeypatch.setattr(bench_run, "_run", lambda *a, **k: True)
     bench_run.main(["--task", "cnn"])  # no SystemExit
 
 

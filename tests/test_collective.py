@@ -30,9 +30,9 @@ _SUBPROC = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.core import aircomp, collective
-    from repro.launch.mesh import activate_mesh
+    from repro.launch.mesh import make_host_mesh
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_host_mesh(model=1)  # (data=8, model=1)
     n, dim = 8, 64
     key = jax.random.PRNGKey(0)
     k1, k2, k3 = jax.random.split(key, 3)
@@ -52,7 +52,7 @@ _SUBPROC = textwrap.dedent(
     a = aircomp.denoise_scalar(rho, jnp.abs(h), mask, 1.0)
     amp = jnp.sqrt(v_g)/a
 
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         agg = collective.make_sharded_aggregator(mesh, "data")
         y_dist = agg(g, mask*rho, jnp.asarray(0.0), jax.random.PRNGKey(5))
     # zero-noise comparison isolates the weighted psum
@@ -66,10 +66,11 @@ _SUBPROC = textwrap.dedent(
 
 def test_sharded_aggregator_matches_reference_on_8dev_mesh():
     env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath("src")
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
     res = subprocess.run(
         [sys.executable, "-c", _SUBPROC],
-        capture_output=True, text=True, env=env, cwd="/root/repo",
+        capture_output=True, text=True, env=env,
+        cwd=os.path.join(os.path.dirname(__file__), ".."),
     )
     assert res.returncode == 0, res.stderr
     assert "OK" in res.stdout

@@ -8,7 +8,7 @@ import pytest
 
 from repro import configs
 from repro.launch.dryrun import cost_analysis_dict, parse_collective_bytes
-from repro.launch.mesh import activate_mesh, make_host_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_step
 from repro.models.config import InputShape
 
@@ -34,7 +34,7 @@ SHAPES = {
 def test_lower_compile_small(mesh, arch_id, kind):
     cfg = configs.reduced_config(arch_id)
     shape = SHAPES[kind]
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = build_step(cfg, shape, mesh)
         lowered = bundle.fn.lower(*bundle.arg_structs.values())
         compiled = lowered.compile()

@@ -181,6 +181,18 @@ def test_worker_env_contract_and_device_pool():
     assert SRC in parts and "/elsewhere" in parts
 
 
+def test_launcher_refuses_without_cpu_platform(monkeypatch):
+    """The launcher is a CPU rehearsal tool: without JAX_PLATFORMS=cpu in
+    the launching process it must refuse before spawning anything."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        worker_env("127.0.0.1:9", 2, 1, 4, base_env={})
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        run_workers([sys.executable, "-c", "pass"], n_procs=1,
+                    devices_per_proc=1, timeout=60)
+
+
 def test_run_workers_raises_on_any_failure():
     """The launcher must not report success over a half-failed topology."""
     argv = [

@@ -219,8 +219,8 @@ def test_engine_cache_stats_is_registry_shim():
 def test_counter_lifecycle_reset_scoping():
     """reset_engine_cache zeroes exactly the engine_cache. namespace; the
     process-lifetime compile_cache. counters survive every reset a test (or
-    the autouse fixture) performs — the CI EXPECT_HITS session guard depends
-    on that."""
+    the autouse fixture) performs — the session-end cache report depends on
+    that."""
     from repro.sim.compile_cache import persistent_cache_counters
     from repro.sim.engine import engine_cache_stats, reset_engine_cache
 
