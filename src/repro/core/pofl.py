@@ -595,106 +595,116 @@ def round_algorithm(
 
     data_frac = data.data_frac
 
-    if policy_id is None:
-        noise_free = cfg.policy == "noisefree"
-        agg_noise_power = 0.0 if noise_free else noise_power
-    else:
-        # traced policy: σ_z² = 0 for noisefree cells is a runtime select —
-        # sqrt(0)·z and the 0-noise closed forms are exact, so values match
-        # the static 0.0 of the string path bit for bit
-        agg_noise_power = jnp.where(
-            policy_id == scheduling.NOISEFREE_ID, 0.0, noise_power
-        )
+    with jax.named_scope("aggregation"):
+        if policy_id is None:
+            noise_free = cfg.policy == "noisefree"
+            agg_noise_power = 0.0 if noise_free else noise_power
+        else:
+            # traced policy: σ_z² = 0 for noisefree cells is a runtime select —
+            # sqrt(0)·z and the 0-noise closed forms are exact, so values match
+            # the static 0.0 of the string path bit for bit
+            agg_noise_power = jnp.where(
+                policy_id == scheduling.NOISEFREE_ID, 0.0, noise_power
+            )
 
     # -- step 2: local updates (K SGD steps per device → delta) -------
-    alg_state_in = alg_state  # pre-round state (the quarantine hold value)
-    g, alg_state = local_update_stage(
-        loss_fn, data, cfg, params, k_batch, t,
-        alg_state=alg_state, algorithm_id=algorithm_id,
-    )  # (N, D) — the legacy single gradient when fedavg/local_steps=1
-    dim = g.shape[-1]
+    with jax.named_scope("local_update"):
+        alg_state_in = alg_state  # pre-round state (the quarantine hold value)
+        g, alg_state = local_update_stage(
+            loss_fn, data, cfg, params, k_batch, t,
+            alg_state=alg_state, algorithm_id=algorithm_id,
+        )  # (N, D) — the legacy single gradient when fedavg/local_steps=1
+        dim = g.shape[-1]
 
     # -- step 3: uploaded scalar statistics ---------------------------
-    if model_shard is not None:
-        # pad D to |model|·tile_d, place P(None, "model"); stats become
-        # masked shard-local reductions + small psums over the model axis
-        g = model_shard.pad_features(g, dim)
-        stats = _model_sharded_local_stats(model_shard, g, dim)
-    else:
-        stats = aircomp.local_stats(g)
+    with jax.named_scope("statistics"):
+        if model_shard is not None:
+            # pad D to |model|·tile_d, place P(None, "model"); stats become
+            # masked shard-local reductions + small psums over the model axis
+            g = model_shard.pad_features(g, dim)
+            stats = _model_sharded_local_stats(model_shard, g, dim)
+        else:
+            stats = aircomp.local_stats(g)
 
     # -- step 4: scheduling -------------------------------------------
-    h_abs = jnp.abs(h)
-    sched_out = scheduling_stage(
-        cfg, stats, h_abs, data_frac, dim, alpha, noise_power, k_sched,
-        avail=avail, policy_id=policy_id, return_probs=diagnostics,
-    )
-    rho, mask = sched_out[0], sched_out[1]
+    with jax.named_scope("scheduling"):
+        h_abs = jnp.abs(h)
+        sched_out = scheduling_stage(
+            cfg, stats, h_abs, data_frac, dim, alpha, noise_power, k_sched,
+            avail=avail, policy_id=policy_id, return_probs=diagnostics,
+        )
+        rho, mask = sched_out[0], sched_out[1]
 
     # -- steps 5-6: AirComp aggregation + model update ----------------
-    y_hat, e_com = aggregation_stage(
-        cfg, g, rho, h, mask, k_noise, agg_noise_power,
-        model_shard=model_shard, stats=stats, dim=dim,
-    )
-    if model_shard is not None:
-        # ŷ comes back padded (its tail is sqrt(V_g)/a·0 + M_g, not zero) —
-        # slice to the true D before the update and the norm tap
-        y_hat = y_hat[:dim]
-    if fault_round is not None:
-        # deterministic NaN injection: a value select on the traced fault
-        # point, so the no-fault program (fault_round = -1) is the same
-        # executable and every unpoisoned lane is bitwise unchanged
-        y_hat = jnp.where(
-            t == jnp.asarray(fault_round, jnp.float32),
-            jnp.full_like(y_hat, jnp.nan),
-            y_hat,
+    with jax.named_scope("aggregation"):
+        y_hat, e_com = aggregation_stage(
+            cfg, g, rho, h, mask, k_noise, agg_noise_power,
+            model_shard=model_shard, stats=stats, dim=dim,
         )
-    # e_var on the padded g is exact: padded columns are zero in every term
-    e_var = scheduling.global_update_variance(g, rho, mask, data_frac, cfg.n_scheduled)
-
-    new_params = apply_update_stage(cfg, params, y_hat, t, model_shard=model_shard)
-    health = None
-    if cfg.on_nonfinite == "skip":
-        # quarantine: a non-finite aggregate (injected or organic) must not
-        # poison the carry — hold BOTH the params and the local-algorithm
-        # state, i.e. the round never happened for the model. The PRNG chain
-        # (engine carry) is untouched either way, so quarantined sweeps stay
-        # deterministic.
-        finite = jnp.all(jnp.isfinite(y_hat))
-        new_params, alg_state = jax.lax.cond(
-            finite,
-            lambda upd, _prev: upd,
-            lambda _upd, prev: prev,
-            (new_params, alg_state),
-            (params, alg_state_in),
-        )
-        health = RoundHealth(
-            nonfinite=(~finite).astype(jnp.float32)
-        )
-    elif cfg.on_nonfinite != "propagate":
-        raise ValueError(
-            f"POFLConfig.on_nonfinite must be 'propagate' or 'skip', "
-            f"got {cfg.on_nonfinite!r}"
+        if model_shard is not None:
+            # ŷ comes back padded (its tail is sqrt(V_g)/a·0 + M_g, not zero) —
+            # slice to the true D before the update and the norm tap
+            y_hat = y_hat[:dim]
+        if fault_round is not None:
+            # deterministic NaN injection: a value select on the traced fault
+            # point, so the no-fault program (fault_round = -1) is the same
+            # executable and every unpoisoned lane is bitwise unchanged
+            y_hat = jnp.where(
+                t == jnp.asarray(fault_round, jnp.float32),
+                jnp.full_like(y_hat, jnp.nan),
+                y_hat,
+            )
+    with jax.named_scope("round_record"):
+        # e_var on the padded g is exact: padded columns are zero in every term
+        e_var = scheduling.global_update_variance(
+            g, rho, mask, data_frac, cfg.n_scheduled
         )
 
-    a = aircomp.denoise_scalar(rho, h_abs, mask, cfg.tx_power)
-    diag = None
-    if diagnostics:
-        _, v_g = aircomp.global_stats(stats, rho, mask)
-        diag = diagnostics_taps(
-            sched_out[2], stats.norm, v_g, a, h_abs, cfg.tx_power,
-            agg_noise_power,
+    with jax.named_scope("apply_update"):
+        new_params = apply_update_stage(cfg, params, y_hat, t, model_shard=model_shard)
+        health = None
+        if cfg.on_nonfinite == "skip":
+            # quarantine: a non-finite aggregate (injected or organic) must not
+            # poison the carry — hold BOTH the params and the local-algorithm
+            # state, i.e. the round never happened for the model. The PRNG chain
+            # (engine carry) is untouched either way, so quarantined sweeps stay
+            # deterministic.
+            finite = jnp.all(jnp.isfinite(y_hat))
+            new_params, alg_state = jax.lax.cond(
+                finite,
+                lambda upd, _prev: upd,
+                lambda _upd, prev: prev,
+                (new_params, alg_state),
+                (params, alg_state_in),
+            )
+            health = RoundHealth(
+                nonfinite=(~finite).astype(jnp.float32)
+            )
+        elif cfg.on_nonfinite != "propagate":
+            raise ValueError(
+                f"POFLConfig.on_nonfinite must be 'propagate' or 'skip', "
+                f"got {cfg.on_nonfinite!r}"
+            )
+
+    with jax.named_scope("round_record"):
+        a = aircomp.denoise_scalar(rho, h_abs, mask, cfg.tx_power)
+        diag = None
+        if diagnostics:
+            _, v_g = aircomp.global_stats(stats, rho, mask)
+            diag = diagnostics_taps(
+                sched_out[2], stats.norm, v_g, a, h_abs, cfg.tx_power,
+                agg_noise_power,
+            )
+        metrics = RoundMetrics(
+            loss=jnp.zeros(()),  # filled by caller's eval if desired
+            e_com=e_com,
+            e_var=e_var,
+            grad_norm=jnp.linalg.norm(y_hat),
+            n_scheduled=jnp.sum(mask),
+            a_scalar=a,
+            diag=diag,
+            health=health,
         )
-    metrics = RoundMetrics(
-        loss=jnp.zeros(()),  # filled by caller's eval if desired
-        e_com=e_com,
-        e_var=e_var,
-        grad_norm=jnp.linalg.norm(y_hat),
-        n_scheduled=jnp.sum(mask),
-        a_scalar=a,
-        diag=diag,
-        health=health,
-    )
     return new_params, alg_state, metrics
 
 

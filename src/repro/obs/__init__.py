@@ -1,6 +1,6 @@
 """repro.obs — the unified flight-recorder.
 
-Three layers, all opt-in and zero-cost when off:
+Four layers, all opt-in and cheap when off:
 
   * host side (:mod:`repro.obs.spans`, :mod:`repro.obs.registry`): timing
     spans and a typed counter/gauge registry that the engine's scattered
@@ -9,15 +9,19 @@ Three layers, all opt-in and zero-cost when off:
   * in-trace (:class:`ObsConfig` + :class:`repro.core.metrics.RoundDiagnostics`):
     cheap per-round scalar taps computed INSIDE the compiled lattice program,
     gated by a static flag that joins the engine cache key;
-  * reporting (:mod:`repro.obs.report`, :mod:`repro.obs.profile`):
-    ``python -m repro.obs.report`` renders a run's JSONL into summary tables
-    and CI gates; ``REPRO_OBS_PROFILE=1`` captures ``jax.profiler`` traces.
+  * on the device clock (:mod:`repro.obs.stages`): every span is also a
+    ``jax.profiler.TraceAnnotation`` once jax is imported, and each stage of
+    the round is a ``jax.named_scope``, so any ``jax.profiler`` trace of a
+    run (``jax.profiler.trace(dir)``, ``perfbench --trace 1``) shows the
+    host spans and names each device op's stage; the engine registers every
+    compiled lattice module's op → stage map here;
+  * reporting (:mod:`repro.obs.report`): ``python -m repro.obs.report``
+    renders a run's JSONL into summary tables and CI gates.
 
 No module here imports jax at import time — obs sits below ``repro.sim`` in
 the layering and stays safe to import before distributed backend init.
 """
 from repro.obs.config import DEFAULT_OBS, ObsConfig
-from repro.obs.profile import maybe_profile, profiling_enabled
 from repro.obs.registry import (
     Counter,
     Gauge,
@@ -31,7 +35,6 @@ from repro.obs.registry import (
 )
 from repro.obs.sink import (
     ENV_OBS_DIR,
-    ENV_OBS_PROFILE,
     close_sink,
     emit,
     event_files,
@@ -61,8 +64,5 @@ __all__ = [
     "read_events",
     "event_files",
     "close_sink",
-    "maybe_profile",
-    "profiling_enabled",
     "ENV_OBS_DIR",
-    "ENV_OBS_PROFILE",
 ]

@@ -5,7 +5,11 @@
 ``DIR`` defaults to ``$REPRO_OBS_DIR``. The report groups by process
 (multihost runs write one file per worker) and summarizes:
 
-  * spans            — count / total / mean seconds per span name
+  * spans            — count / total / mean seconds per span name, and the
+                       spans each was opened in (``lattice.place``,
+                       ``lattice.dispatch``, ``lattice.wait``,
+                       ``lattice.stream_out``, ``lattice.assemble`` inside
+                       ``lattice.sweep``)
   * counters         — final totals (engine cache, compiles, traces, …)
   * lattice runs     — per ``run_lattice`` call: cells, cold/warm,
                        trace and compile deltas
@@ -31,12 +35,13 @@ from repro.obs.sink import event_files, obs_dir, read_events
 def collect(events) -> dict:
     """Fold an event stream into per-process summary structures."""
     out: dict = {
-        "spans": defaultdict(lambda: {"count": 0, "seconds": 0.0, "max": 0.0}),
+        "spans": defaultdict(
+            lambda: {"count": 0, "seconds": 0.0, "max": 0.0, "parents": set()}
+        ),
         "counters": {},  # (process, name) -> last seen total
         "gauges": {},
         "lattice": [],
         "diag": [],
-        "profiles": [],
         "processes": set(),
     }
     for ev in events:
@@ -49,6 +54,7 @@ def collect(events) -> dict:
             s["count"] += 1
             s["seconds"] += ev.get("seconds", 0.0)
             s["max"] = max(s["max"], ev.get("seconds", 0.0))
+            s["parents"].add(ev.get("parent"))
         elif kind == "counter":
             out["counters"][(proc, name)] = ev.get("total", 0)
         elif kind == "gauge":
@@ -57,8 +63,6 @@ def collect(events) -> dict:
             out["lattice"].append(ev)
         elif kind == "diag":
             out["diag"].append(ev)
-        elif kind == "profile":
-            out["profiles"].append(ev)
     return out
 
 
@@ -78,12 +82,13 @@ def render(summary: dict) -> str:
     if summary["spans"]:
         lines.append("\n## spans (host wall-clock)")
         lines.append(f"{'process':>7}  {'span':<28} {'count':>6} "
-                     f"{'total_s':>9} {'mean_s':>9} {'max_s':>9}")
+                     f"{'total_s':>9} {'mean_s':>9} {'max_s':>9}  parent")
         for (proc, name), s in sorted(summary["spans"].items()):
             mean = s["seconds"] / max(s["count"], 1)
+            parents = ", ".join(sorted(p for p in s["parents"] if p)) or "-"
             lines.append(
                 f"{proc:>7}  {name:<28} {s['count']:>6} "
-                f"{s['seconds']:>9.3f} {mean:>9.3f} {s['max']:>9.3f}"
+                f"{s['seconds']:>9.3f} {mean:>9.3f} {s['max']:>9.3f}  {parents}"
             )
 
     if summary["counters"]:
@@ -121,11 +126,6 @@ def render(summary: dict) -> str:
                 lines.append(
                     f"  {tap:<20} mean={mean:.4e}  rounds={_fmt_rounds(series)}"
                 )
-
-    if summary["profiles"]:
-        lines.append("\n## profiler captures")
-        for ev in summary["profiles"]:
-            lines.append(f"  {ev.get('name')}: {ev.get('trace_dir')}")
     return "\n".join(lines)
 
 

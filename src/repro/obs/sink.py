@@ -3,8 +3,6 @@
 One env contract:
 
     REPRO_OBS_DIR=<dir>      stream every obs event into <dir> as JSONL
-    REPRO_OBS_PROFILE=1      additionally capture jax.profiler traces around
-                             lattice dispatches (see ``repro.obs.profile``)
 
 When ``REPRO_OBS_DIR`` is unset, :func:`emit` still returns the assembled
 event (the in-memory registry keeps working) but writes nothing — the
@@ -31,7 +29,6 @@ import time
 from typing import Iterator, TextIO
 
 ENV_OBS_DIR = "REPRO_OBS_DIR"
-ENV_OBS_PROFILE = "REPRO_OBS_PROFILE"
 
 # the multihost env contract (literals duplicated from repro.sim.multihost:
 # obs sits BELOW sim in the layering and must not import it)

@@ -16,9 +16,11 @@ session (``tests/conftest.py``) and the ``repro.launch.distributed``
 workers (which inherit the variable) call :func:`enable_compile_cache`
 with no default, so they cache only where the variable says.
 
-Hit accounting: :func:`enable_compile_cache` registers a
-``jax.monitoring`` listener counting the ``/jax/compilation_cache/*``
-events, exposed by :func:`persistent_cache_counters` — within one process a
+Hit accounting: :func:`install_listener` registers a ``jax.monitoring``
+listener counting the ``/jax/compilation_cache/*`` events, exposed by
+:func:`persistent_cache_counters`. :func:`enable_compile_cache` installs it,
+and so does the engine's first lattice compile, whoever enabled the cache
+(``perfbench`` sets the cache up itself) — within one process a
 program compiled earlier in the SAME process hits jax's in-memory caches
 first, so persistent hits are expected on *fresh* processes.
 """
@@ -51,7 +53,8 @@ def _count_cache_events(event: str, **kwargs: Any) -> None:
         counter_add("compile_cache.misses")
 
 
-def _install_listener() -> None:
+def install_listener() -> None:
+    """Count persistent-cache hits and misses from now on (idempotent)."""
     global _LISTENER_INSTALLED
     if _LISTENER_INSTALLED:
         return
@@ -76,7 +79,7 @@ def enable_compile_cache(default_dir: str | None = None) -> str | None:
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _install_listener()
+    install_listener()
     return path
 
 

@@ -54,9 +54,10 @@ from repro.core.pofl import (
     DeviceData, History, ModelShard, POFLConfig, round_algorithm,
 )
 from repro.obs.config import DEFAULT_OBS, ObsConfig
-from repro.obs.profile import maybe_profile, profiling_enabled
 from repro.obs.registry import counter_add, metric_value, reset_metrics
 from repro.obs.spans import span
+from repro.obs.stages import register_module
+from repro.sim.compile_cache import install_listener
 from repro.sim.scenario import make_channel_process
 from repro.sim.tasks import TaskEval, zero_eval_record
 
@@ -375,7 +376,8 @@ class SimEngine:
             t = t_int.astype(jnp.float32)
             key, k_round = jax.random.split(st.key)
             k_batch, k_chan, k_sched, k_noise = jax.random.split(k_round, 4)
-            chan, h, avail = self.process.step(st.chan, k_chan)
+            with jax.named_scope("channel"):
+                chan, h, avail = self.process.step(st.chan, k_chan)
             params, alg, m = round_algorithm(
                 self.loss_fn, self.data, self.cfg, st.params, h,
                 k_batch, k_sched, k_noise, t,
@@ -390,28 +392,29 @@ class SimEngine:
                 algorithm_id=algorithm_id,
                 fault_round=fault_round,
             )
-            ev_rec = None
-            if self.eval_fn is None:
-                loss = acc = jnp.zeros(())
-            elif self._task_eval is not None:
-                # model-task eval: one cond produces the full EvalRecord; its
-                # loss/acc also fill the legacy always-present record fields
-                ev_rec = jax.lax.cond(
-                    ev,
-                    self._task_eval.record,
-                    lambda p: zero_eval_record(),
-                    params,
-                )
-                loss, acc = ev_rec.loss, ev_rec.acc
-            else:
-                loss, acc = jax.lax.cond(
-                    ev,
-                    lambda p: tuple(
-                        jnp.asarray(v, jnp.float32) for v in self.eval_fn(p)
-                    ),
-                    lambda p: (jnp.zeros(()), jnp.zeros(())),
-                    params,
-                )
+            with jax.named_scope("eval"):
+                ev_rec = None
+                if self.eval_fn is None:
+                    loss = acc = jnp.zeros(())
+                elif self._task_eval is not None:
+                    # model-task eval: one cond produces the full EvalRecord; its
+                    # loss/acc also fill the legacy always-present record fields
+                    ev_rec = jax.lax.cond(
+                        ev,
+                        self._task_eval.record,
+                        lambda p: zero_eval_record(),
+                        params,
+                    )
+                    loss, acc = ev_rec.loss, ev_rec.acc
+                else:
+                    loss, acc = jax.lax.cond(
+                        ev,
+                        lambda p: tuple(
+                            jnp.asarray(v, jnp.float32) for v in self.eval_fn(p)
+                        ),
+                        lambda p: (jnp.zeros(()), jnp.zeros(())),
+                        params,
+                    )
             rec = RoundRecord(
                 e_com=m.e_com, e_var=m.e_var, grad_norm=m.grad_norm,
                 n_scheduled=m.n_scheduled, loss=loss, acc=acc, diag=m.diag,
@@ -561,27 +564,23 @@ class SimEngine:
                 "run_lattice_chunk is always policy-fused: pass policy_b "
                 "(a constant array selects one policy)"
             )
-        if fault_b is None:
-            fault_b = jnp.full(np.shape(policy_b), -1, jnp.int32)
-        args = (
-            state_b, jnp.asarray(t_ints), jnp.asarray(do_eval),
-            jnp.asarray(active), noise_b, alpha_b, policy_b,
-        )
-        if algorithm_b is not None:
-            mode = "chunk_alg"
-            args = args + (algorithm_b, jnp.asarray(fault_b))
-        else:
-            mode = "chunk"
-            args = args + (jnp.asarray(fault_b),)
-        compiled = self._aot_lattice_executable(mode, args)
         n_cells = int(np.shape(policy_b)[0]) if np.ndim(policy_b) else 1
-        with maybe_profile("lattice"), span(
-            "lattice.dispatch", fused=True, cells=n_cells, chunked=True
-        ):
-            out = compiled(*args)
-            if profiling_enabled():
-                out = jax.block_until_ready(out)
-            return out
+        # the launch, as in :meth:`run_lattice_cells`
+        with span("lattice.dispatch", fused=True, cells=n_cells, chunked=True):
+            if fault_b is None:
+                fault_b = jnp.full(np.shape(policy_b), -1, jnp.int32)
+            args = (
+                state_b, jnp.asarray(t_ints), jnp.asarray(do_eval),
+                jnp.asarray(active), noise_b, alpha_b, policy_b,
+            )
+            if algorithm_b is not None:
+                mode = "chunk_alg"
+                args = args + (algorithm_b, jnp.asarray(fault_b))
+            else:
+                mode = "chunk"
+                args = args + (jnp.asarray(fault_b),)
+            compiled = self._aot_lattice_executable(mode, args)
+            return compiled(*args)
 
     @staticmethod
     def _arg_signature(leaf) -> tuple:
@@ -618,7 +617,11 @@ class SimEngine:
         straight to it — no jit-cache lookup, no re-trace, and honest
         compile-vs-steady-state accounting for ``benchmarks/run.py``. The
         executable also exposes XLA's per-program ``cost_analysis`` /
-        ``memory_analysis`` (see :meth:`lattice_cost_analysis`).
+        ``memory_analysis`` (see :meth:`lattice_cost_analysis`). Each compile
+        adds 0 or 1 to ``lattice.cache_misses`` (1 when it missed JAX's
+        persistent cache) and registers its modules with
+        ``repro.obs.stages``, so a profiler trace of the program can be read
+        stage by stage.
         """
         leaves, treedef = jax.tree.flatten(args)
         # mesh identity rides at the END of the key (append-only contract):
@@ -640,6 +643,8 @@ class SimEngine:
                 "chunk": self._chunk_lattice_jit,
                 "chunk_alg": self._chunk_alg_lattice_jit,
             }[mode]
+            install_listener()
+            misses0 = metric_value("compile_cache.misses")
             t0 = time.perf_counter()
             with span("lattice.compile", fused=bool(mode)):
                 compiled = fn.lower(*args).compile()
@@ -648,6 +653,15 @@ class SimEngine:
             self.n_compiles += 1
             counter_add("lattice.n_compiles")
             counter_add("lattice.compile_seconds", dt, emit_event=False)
+            # 0 or 1: did this compile miss the persistent cache?
+            counter_add(
+                "lattice.cache_misses",
+                int(metric_value("compile_cache.misses") > misses0),
+            )
+            # the op -> round-stage map of this program, for trace readers
+            # (``repro.obs.stages``; rendered only when first read)
+            for module in compiled.runtime_executable().hlo_modules():
+                register_module(module.name, module.to_string)
             self._lattice_executables[key] = compiled
             while len(self._lattice_executables) > _LATTICE_EXECUTABLES_MAX:
                 self._lattice_executables.popitem(last=False)
@@ -678,39 +692,33 @@ class SimEngine:
         (``n_lattice_traces`` stays flat) and recompile zero times
         (``n_compiles`` stays flat).
         """
-        args = (
-            jax.tree.map(jnp.asarray, params0),
-            jnp.asarray(t_ints), jnp.asarray(do_eval),
-            noise_b, alpha_b, seed_b,
-        )
-        if algorithm_b is not None:
-            if policy_b is None:
-                raise ValueError(
-                    "algorithm_b requires policy_b: the algorithm-fused "
-                    "program fuses the policy axis too (constant policy_b "
-                    "is fine)"
-                )
-            mode = "fused_alg"
-            args = args + (policy_b, algorithm_b)
-        elif policy_b is not None:
-            mode = True
-            args = args + (policy_b,)
-        else:
-            mode = False
-        compiled = self._aot_lattice_executable(mode, args)
+        if algorithm_b is not None and policy_b is None:
+            raise ValueError(
+                "algorithm_b requires policy_b: the algorithm-fused "
+                "program fuses the policy axis too (constant policy_b "
+                "is fine)"
+            )
         n_cells = int(np.shape(seed_b)[0]) if np.ndim(seed_b) else 1
-        # the dispatch span measures HOST dispatch wall only (jax dispatch is
-        # async — device execution completes under the caller's
-        # block_until_ready / device_get, covered by the lattice.sweep span).
-        # Under REPRO_OBS_PROFILE the dispatch blocks inside the profiler
-        # context so the capture contains the device execution too.
-        with maybe_profile("lattice"), span(
-            "lattice.dispatch", fused=bool(mode), cells=n_cells
-        ):
-            out = compiled(*args)
-            if profiling_enabled():
-                out = jax.block_until_ready(out)
-            return out
+        # the dispatch span covers the host's whole launch: argument
+        # conversion, the executable lookup (a first call compiles there,
+        # inside its own ``lattice.compile`` span) and the asynchronous call;
+        # the device's work completes under the caller's ``lattice.wait``
+        with span("lattice.dispatch", fused=policy_b is not None, cells=n_cells):
+            args = (
+                jax.tree.map(jnp.asarray, params0),
+                jnp.asarray(t_ints), jnp.asarray(do_eval),
+                noise_b, alpha_b, seed_b,
+            )
+            if algorithm_b is not None:
+                mode = "fused_alg"
+                args = args + (policy_b, algorithm_b)
+            elif policy_b is not None:
+                mode = True
+                args = args + (policy_b,)
+            else:
+                mode = False
+            compiled = self._aot_lattice_executable(mode, args)
+            return compiled(*args)
 
     def lattice_cost_analysis(self) -> dict:
         """XLA ``cost_analysis`` (flops/bytes) of the most recent lattice

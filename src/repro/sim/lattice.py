@@ -308,10 +308,32 @@ def run_lattice(
         ]
         return _concat_algorithms(algs, per_alg)
 
+    with span(
+        "lattice.sweep", fused=fuse_policies, policies=len(spec.policies),
+        algorithms=len(algs),
+    ) as sweep:
+        return _run_sweep(
+            loss_fn, data, params0, spec, base_cfg, eval_fn, channel_cfg,
+            scenario, scenario_params, mesh, fuse_policies, obs, algs,
+            _forced_algorithm_axis, sweep,
+        )
+
+
+def _run_sweep(
+    loss_fn, data, params0, spec, base_cfg, eval_fn, channel_cfg, scenario,
+    scenario_params, mesh, fuse_policies, obs, algs, forced_algorithm_axis,
+    sweep,
+) -> LatticeRecords:
+    """The body of one :func:`run_lattice` sweep, inside its
+    ``lattice.sweep`` span. The host work splits into child spans:
+    ``lattice.place`` (the cell axes onto the devices), ``lattice.dispatch``
+    (the engine), ``lattice.wait`` (until the device is done),
+    ``lattice.stream_out`` (records to the host) and ``lattice.assemble``
+    (the grid reshapes)."""
     # the algorithm axis is traced iff >1 algorithm (fused) or forced by the
     # per-algorithm fallback loop; single-algorithm user specs keep the
     # historical static dispatch (default ("fedavg",) → today's exact program)
-    traced_algs = len(algs) > 1 or _forced_algorithm_axis
+    traced_algs = len(algs) > 1 or forced_algorithm_axis
     base_alg = FUSED_ALGORITHM if len(algs) > 1 else algs[0]
 
     if isinstance(mesh, int):
@@ -369,44 +391,46 @@ def run_lattice(
     n_real = cells[0].size
 
     multihost = mesh_spans_processes(mesh)
-    if mesh is not None:
-        # pad the cell axis to a multiple of the CELLS-axis size with dead
-        # cells (repeats of the last real cell — same shapes, outputs
-        # discarded). On a 1-D mesh that is the device count; on a 2-D
-        # (cells, model) mesh only the first axis shards cells.
-        n_shards = int(mesh.shape[mesh.axis_names[0]])
-        pad = (-n_real) % n_shards
-        if pad:
-            cells = [np.concatenate([c, np.repeat(c[-1:], pad)]) for c in cells]
-        cell_sharding = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
+    sweep.annotate(cells=n_real, multihost=multihost)
+    with span("lattice.place"):
+        if mesh is not None:
+            # pad the cell axis to a multiple of the CELLS-axis size with dead
+            # cells (repeats of the last real cell — same shapes, outputs
+            # discarded). On a 1-D mesh that is the device count; on a 2-D
+            # (cells, model) mesh only the first axis shards cells.
+            n_shards = int(mesh.shape[mesh.axis_names[0]])
+            pad = (-n_real) % n_shards
+            if pad:
+                cells = [np.concatenate([c, np.repeat(c[-1:], pad)]) for c in cells]
+            cell_sharding = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
 
-        if multihost:
-            # every process holds the same deterministic grid; each commits
-            # only the shards its own devices own
-            def place(c):
-                return shard_to_global(c, cell_sharding)
+            if multihost:
+                # every process holds the same deterministic grid; each commits
+                # only the shards its own devices own
+                def place(c):
+                    return shard_to_global(c, cell_sharding)
+            else:
+                def place(c):
+                    return jax.device_put(jnp.asarray(c), cell_sharding)
+
+            if "model" in mesh.axis_names and int(mesh.shape["model"]) > 1:
+                # model-sharded lattice: commit the initial params to their
+                # param_spec placement so the very first dispatch — not just the
+                # constrained carry — holds only D/|model| columns per device
+                from repro.launch.sharding import param_spec  # late: launch↔sim
+
+                def place_leaf(leaf):
+                    sh = NamedSharding(mesh, param_spec(np.shape(leaf), mesh))
+                    if multihost:
+                        return shard_to_global(leaf, sh)
+                    return jax.device_put(jnp.asarray(leaf), sh)
+
+                params0 = jax.tree.map(place_leaf, params0)
         else:
             def place(c):
-                return jax.device_put(jnp.asarray(c), cell_sharding)
+                return jnp.asarray(c)
 
-        if "model" in mesh.axis_names and int(mesh.shape["model"]) > 1:
-            # model-sharded lattice: commit the initial params to their
-            # param_spec placement so the very first dispatch — not just the
-            # constrained carry — holds only D/|model| columns per device
-            from repro.launch.sharding import param_spec  # late: launch↔sim
-
-            def place_leaf(leaf):
-                sh = NamedSharding(mesh, param_spec(np.shape(leaf), mesh))
-                if multihost:
-                    return shard_to_global(leaf, sh)
-                return jax.device_put(jnp.asarray(leaf), sh)
-
-            params0 = jax.tree.map(place_leaf, params0)
-    else:
-        def place(c):
-            return jnp.asarray(c)
-
-    cells_b = [place(c) for c in cells]
+        cells_b = [place(c) for c in cells]
     n_padded = cells[0].size
 
     grid_shape = (len(spec.noise_powers), len(spec.alphas), len(spec.seeds))
@@ -491,40 +515,37 @@ def run_lattice(
         )
         eng = one_engine(cfg)
         warm, tr0, co0 = eng.n_lattice_traces > 0, eng.n_lattice_traces, eng.n_compiles
-        with span(
-            "lattice.sweep", cells=n_real, fused=True,
-            policies=len(spec.policies), algorithms=len(algs),
-            multihost=multihost,
-        ):
-            recs = eng.run_lattice_cells(
-                params0, t_ints, do_eval, noise_b, alpha_b, seed_b,
-                policy_b=policy_b, algorithm_b=algorithm_b,
-            )
-            if multihost:
-                # drain the (collective-free) compute before the gather's single
-                # collective program launches anywhere — overlapping launches are
-                # what the CPU gloo runtime cannot be trusted with
-                jax.block_until_ready(recs)
+        recs = eng.run_lattice_cells(
+            params0, t_ints, do_eval, noise_b, alpha_b, seed_b,
+            policy_b=policy_b, algorithm_b=algorithm_b,
+        )
+        # multi-host: drain the (collective-free) compute before the
+        # gather's single collective program launches anywhere —
+        # overlapping launches are what the CPU gloo runtime cannot be
+        # trusted with
+        _wait(recs, multihost)
+        with span("lattice.stream_out"):
             # single stream-out: device → host exactly once for the whole
             # lattice, dropping any dead padding cells
             recs = gather_records(recs, mesh) if multihost else jax.device_get(recs)
         _emit_run(eng, warm, tr0, co0, fused=True)
-        recs = jax.tree.map(lambda a: a[:n_real], recs)
+        with span("lattice.assemble"):
+            recs = jax.tree.map(lambda a: a[:n_real], recs)
 
-        def gather(field: str, eval_only: bool) -> np.ndarray:
-            # (A·P·B, T) flat, algorithm-major then policy-major
-            stacked = _shape_flat(getattr(recs, field))
-            return stacked[..., do_eval] if eval_only else stacked
+            def gather(field: str, eval_only: bool) -> np.ndarray:
+                # (A·P·B, T) flat, algorithm-major then policy-major
+                stacked = _shape_flat(getattr(recs, field))
+                return stacked[..., do_eval] if eval_only else stacked
 
-        diag = None if recs.diag is None else _grid_diag(list(recs.diag), _shape_flat)
-        ev = None if recs.eval is None else _grid_eval(recs.eval, _shape_flat)
-        health = (
-            None if recs.health is None
-            else _grid_health(recs.health, _shape_flat)
-        )
-        return _assemble_records(
-            spec, algs, gather, eval_rounds, diag=diag, eval=ev, health=health
-        )
+            diag = None if recs.diag is None else _grid_diag(list(recs.diag), _shape_flat)
+            ev = None if recs.eval is None else _grid_eval(recs.eval, _shape_flat)
+            health = (
+                None if recs.health is None
+                else _grid_health(recs.health, _shape_flat)
+            )
+            return _assemble_records(
+                spec, algs, gather, eval_rounds, diag=diag, eval=ev, health=health
+            )
 
     if traced_algs:
         noise_b, alpha_b, seed_b, algorithm_b = cells_b
@@ -532,76 +553,87 @@ def run_lattice(
         noise_b, alpha_b, seed_b = cells_b
         algorithm_b = None
     per_policy = []
-    with span(
-        "lattice.sweep", cells=n_real, fused=False,
-        policies=len(spec.policies), algorithms=len(algs),
-        multihost=multihost,
-    ):
-        for policy in spec.policies:
-            # same traced-dispatch cell program, constant policy axis — one
-            # (smaller) compile per policy, per-cell values bit-identical to the
-            # fused program's lanes
+    for policy in spec.policies:
+        # same traced-dispatch cell program, constant policy axis — one
+        # (smaller) compile per policy, per-cell values bit-identical to the
+        # fused program's lanes
+        with span("lattice.place"):
             policy_b = place(
                 np.full((n_padded,), scheduling.policy_id(policy), np.int32)
             )
-            cfg = dataclasses.replace(
-                base_cfg, policy=policy, local_algorithm=base_alg,
-                n_devices=data.n_devices,
-            )
-            eng = one_engine(cfg)
-            warm, tr0, co0 = (
-                eng.n_lattice_traces > 0, eng.n_lattice_traces, eng.n_compiles
-            )
-            recs = eng.run_lattice_cells(
-                params0, t_ints, do_eval, noise_b, alpha_b, seed_b,
-                policy_b=policy_b, algorithm_b=algorithm_b,
-            )
-            _emit_run(eng, warm, tr0, co0, fused=False, policy=policy)
-            if multihost:
-                jax.block_until_ready(recs)
-            per_policy.append(recs)  # stays on device until the final stream-out
+        cfg = dataclasses.replace(
+            base_cfg, policy=policy, local_algorithm=base_alg,
+            n_devices=data.n_devices,
+        )
+        eng = one_engine(cfg)
+        warm, tr0, co0 = (
+            eng.n_lattice_traces > 0, eng.n_lattice_traces, eng.n_compiles
+        )
+        recs = eng.run_lattice_cells(
+            params0, t_ints, do_eval, noise_b, alpha_b, seed_b,
+            policy_b=policy_b, algorithm_b=algorithm_b,
+        )
+        _emit_run(eng, warm, tr0, co0, fused=False, policy=policy)
+        if multihost:
+            _wait(recs, multihost)
+        per_policy.append(recs)  # stays on device until the final stream-out
 
-        # single stream-out: device → host exactly once for the whole lattice,
-        # dropping any dead padding cells (multi-host: a tiled allgather first —
-        # no process can address the other hosts' record shards directly)
+    _wait(per_policy, multihost)
+    # single stream-out: device → host exactly once for the whole lattice,
+    # dropping any dead padding cells (multi-host: a tiled allgather first —
+    # no process can address the other hosts' record shards directly)
+    with span("lattice.stream_out"):
         per_policy = (
             gather_records(per_policy, mesh) if multihost else jax.device_get(per_policy)
         )
-    per_policy = jax.tree.map(lambda a: a[:n_real], per_policy)
+    with span("lattice.assemble"):
+        per_policy = jax.tree.map(lambda a: a[:n_real], per_policy)
 
-    def gather(field: str, eval_only: bool) -> np.ndarray:
-        stacked = np.stack([getattr(r, field) for r in per_policy])  # (P, A·B, T)
-        stacked = _shape_stacked(stacked)
-        return stacked[..., do_eval] if eval_only else stacked
+        def gather(field: str, eval_only: bool) -> np.ndarray:
+            stacked = np.stack([getattr(r, field) for r in per_policy])  # (P, A·B, T)
+            stacked = _shape_stacked(stacked)
+            return stacked[..., do_eval] if eval_only else stacked
 
-    diag = None
-    if per_policy and per_policy[0].diag is not None:
-        diag = _grid_diag([
-            np.stack([np.asarray(getattr(r.diag, f)) for r in per_policy])
-            for f in per_policy[0].diag._fields
-        ], _shape_stacked)
-    ev = None
-    if per_policy and per_policy[0].eval is not None:
-        first_ev = per_policy[0].eval
-        ev = _grid_eval(
-            type(first_ev)(*(
-                np.stack([np.asarray(getattr(r.eval, f)) for r in per_policy])
-                for f in first_ev._fields
-            )),
-            _shape_stacked,
-        )
-    health = None
-    if per_policy and per_policy[0].health is not None:
-        first_h = per_policy[0].health
-        health = type(first_h)(*(
-            _shape_stacked(
-                np.stack([np.asarray(getattr(r.health, f)) for r in per_policy])
+        diag = None
+        if per_policy and per_policy[0].diag is not None:
+            diag = _grid_diag([
+                np.stack([np.asarray(getattr(r.diag, f)) for r in per_policy])
+                for f in per_policy[0].diag._fields
+            ], _shape_stacked)
+        ev = None
+        if per_policy and per_policy[0].eval is not None:
+            first_ev = per_policy[0].eval
+            ev = _grid_eval(
+                type(first_ev)(*(
+                    np.stack([np.asarray(getattr(r.eval, f)) for r in per_policy])
+                    for f in first_ev._fields
+                )),
+                _shape_stacked,
             )
-            for f in first_h._fields
-        ))
-    return _assemble_records(
-        spec, algs, gather, eval_rounds, diag=diag, eval=ev, health=health
-    )
+        health = None
+        if per_policy and per_policy[0].health is not None:
+            first_h = per_policy[0].health
+            health = type(first_h)(*(
+                _shape_stacked(
+                    np.stack([np.asarray(getattr(r.health, f)) for r in per_policy])
+                )
+                for f in first_h._fields
+            ))
+        return _assemble_records(
+            spec, algs, gather, eval_rounds, diag=diag, eval=ev, health=health
+        )
+
+
+def _wait(recs, multihost: bool) -> None:
+    """Block until the device has produced ``recs``, inside a
+    ``lattice.wait`` span. On one host their copies to the host start first,
+    as ``jax.device_get`` alone would start them, so waiting apart from the
+    stream-out adds no round trip."""
+    with span("lattice.wait"):
+        if not multihost:
+            for leaf in jax.tree.leaves(recs):
+                leaf.copy_to_host_async()
+        jax.block_until_ready(recs)
 
 
 def _concat_algorithms(

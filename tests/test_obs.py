@@ -405,6 +405,101 @@ def test_run_with_history_counts_traces_in_registry():
 
 
 # --------------------------------------------------------------------------
+# spans on the profiler clock
+# --------------------------------------------------------------------------
+
+SWEEP_CHILDREN = (
+    "lattice.place", "lattice.dispatch", "lattice.wait", "lattice.stream_out",
+    "lattice.assemble",
+)
+
+
+def test_span_events_carry_their_parent(monkeypatch, tmp_path):
+    monkeypatch.setenv("REPRO_OBS_DIR", str(tmp_path))
+    with span("t.outer") as outer:
+        with span("t.inner") as inner:
+            pass
+        with span("t.inner"):
+            pass
+    with span("t.alone"):
+        pass
+    close_sink()
+    assert outer.parent is None and inner.parent == "t.outer"
+    got = [(e["name"], e["parent"]) for e in read_events(str(tmp_path))
+           if e["kind"] == "span"]
+    assert got == [("t.inner", "t.outer"), ("t.inner", "t.outer"),
+                   ("t.outer", None), ("t.alone", None)]
+
+
+def test_lattice_spans_land_in_a_profiler_trace(monkeypatch, tmp_path):
+    """Under ``jax.profiler.trace`` every span of a sweep is an annotation
+    on one host line, the five child spans inside ``lattice.sweep``, and
+    the JSONL events name the same nesting."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core.pofl import POFLConfig
+    from repro.sim.lattice import run_lattice
+
+    loss_fn, data, params0 = _tiny_task()
+    spec = _tiny_spec(n_rounds=2)
+    cfg = POFLConfig(n_devices=8, n_scheduled=3)
+    run_lattice(loss_fn, data, params0, spec, base_cfg=cfg)  # compile first
+    monkeypatch.setenv("REPRO_OBS_DIR", str(tmp_path / "obs"))
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        run_lattice(loss_fn, data, params0, spec, base_cfg=cfg)
+    close_sink()
+
+    (path,) = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"), recursive=True)
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("lattice."):
+                    lines.setdefault((plane.name, line.name), []).append(
+                        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns))
+    assert len(lines) == 1, lines  # one host line holds them all
+    (events,) = lines.values()
+    (sweep,) = [e for e in events if e[0] == "lattice.sweep"]
+    children = sorted((e for e in events if e[0] != "lattice.sweep"), key=lambda e: e[1])
+    assert [e[0] for e in children] == list(SWEEP_CHILDREN)
+    for _, s, e in children:
+        assert sweep[1] <= s <= e <= sweep[2]
+
+    parents = {e["name"]: e["parent"] for e in read_events(str(tmp_path / "obs"))
+               if e["kind"] == "span"}
+    assert parents["lattice.sweep"] is None
+    for name in SWEEP_CHILDREN:
+        assert parents[name] == "lattice.sweep"
+
+
+def test_report_names_each_spans_parent():
+    events = [{"kind": "span", "name": "lattice.sweep", "seconds": 1.0, "parent": None}]
+    events += [{"kind": "span", "name": n, "seconds": 0.1, "parent": "lattice.sweep"}
+               for n in SWEEP_CHILDREN]
+    events.append({"kind": "span", "name": "lattice.dispatch", "seconds": 0.1,
+                   "parent": "resilience.chunk"})
+    rows = {ln.split()[1]: ln for ln in render(collect(events)).splitlines()
+            if ln.split()[1:2] and ln.split()[1].startswith("lattice.")}
+    assert rows["lattice.sweep"].endswith("  -")
+    for name in SWEEP_CHILDREN:
+        assert rows[name].endswith("  lattice.sweep" if name != "lattice.dispatch"
+                                   else "  lattice.sweep, resilience.chunk")
+
+
+def test_import_repro_obs_leaves_jax_unimported():
+    code = "import sys, repro.obs, repro.obs.stages; print('jax' in sys.modules)"
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=dict(os.environ, PYTHONPATH=SRC),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "False"
+
+
+# --------------------------------------------------------------------------
 # bench history satellite
 # --------------------------------------------------------------------------
 
