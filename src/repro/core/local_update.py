@@ -190,6 +190,22 @@ def local_gradient_stage(
     return _device_gradients(loss_fn, params, feats, labels)
 
 
+def local_gradient_block(
+    loss_fn: Callable,
+    data,
+    cfg,
+    params,
+    k_batch: jax.Array,
+    layout,
+):
+    """:func:`local_gradient_stage` with the per-device gradients left in
+    ``layout``'s segments (a ``core.grad_layout.GradBlock``) instead of
+    raveled: the same draw and the same gradients, only not relaid out."""
+    feats, labels = draw_minibatch(data, cfg, k_batch)
+    grads = jax.vmap(lambda fx, fy: jax.grad(loss_fn)(params, fx, fy))(feats, labels)
+    return layout.block(grads)
+
+
 def _effective_gradient_branches(mu, a_dyn, h, c, cbar):
     """The APPEND-ONLY ``lax.switch`` branch table, ``ALGORITHMS`` order.
 

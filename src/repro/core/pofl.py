@@ -54,10 +54,12 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core import aircomp, scheduling
+from repro.core import aircomp, grad_layout, scheduling
 from repro.core.channel import ChannelConfig, ChannelState
 from repro.core.local_update import (  # noqa: F401  (re-exported API)
+    STATELESS,
     AlgState,
+    local_gradient_block,
     local_gradient_stage,
     local_update_stage,
 )
@@ -414,6 +416,67 @@ def scheduling_stage(
     return (rho, mask, probs) if return_probs else (rho, mask)
 
 
+def lane_dense_layout(
+    cfg: POFLConfig,
+    params,
+    model_shard: ModelShard | None = None,
+    traced_algorithm: bool = False,
+) -> grad_layout.Layout | None:
+    """The round's lane-dense gradient layout (``core.grad_layout``), or
+    None for the flat ``(N, D)`` block.
+
+    Taken where the round keeps the block to itself — the static
+    fedavg/fedprox one-gradient round, unsharded — and the aggregation runs
+    the Pallas kernel, compiled on the TPU or interpreted when
+    ``REPRO_PALLAS_INTERPRET`` asks for it. The jnp oracle (``auto`` on the
+    CPU), AlgState, the local-step scan, the traced algorithm switch and the
+    model-sharded route keep the flat block, op for op.
+    """
+    if (
+        model_shard is not None
+        or traced_algorithm
+        or int(cfg.local_steps) != 1
+        or cfg.local_algorithm not in STATELESS
+        or AggregationBackend(cfg.backend) is not AggregationBackend.PALLAS_FUSED
+    ):
+        return None
+    from repro.kernels.aircomp.ops import resolve_mode  # late: kernels↔core
+
+    return grad_layout.plan(params) if resolve_mode("auto") else None
+
+
+def _lane_dense_combine(
+    layout: grad_layout.Layout,
+    g: grad_layout.GradBlock,
+    coeff: jnp.ndarray,
+    m_g: jnp.ndarray,
+    v_g: jnp.ndarray,
+    a: jnp.ndarray,
+    z: jnp.ndarray,
+    use_pallas: str | bool,
+) -> jnp.ndarray:
+    """The fused Eq. 5→8 combine over a :class:`~repro.core.grad_layout.GradBlock`:
+    the canonical noise draw mapped into the segments, the kernel over each
+    segment, ŷ mapped back to canonical order. A flat remainder narrower
+    than one lane tile (a bias) runs the kernel's jnp oracle — the same
+    arithmetic, without a padded launch of its own."""
+    from repro.kernels.aircomp import aircomp_aggregate_fused  # late: kernels↔core
+
+    z_flat, z_dense = layout.segments(z)
+    y_dense = tuple(
+        aircomp_aggregate_fused(gs, coeff, m_g, v_g, a, zs, use_pallas=use_pallas)
+        for gs, zs in zip(g.dense, z_dense)
+    )
+    y_flat = None
+    if g.flat is not None:
+        narrow = g.flat.shape[-1] < grad_layout.LANE
+        y_flat = aircomp_aggregate_fused(
+            g.flat, coeff, m_g, v_g, a, z_flat,
+            use_pallas=False if narrow else use_pallas,
+        )
+    return layout.canonical(y_flat, y_dense)
+
+
 def aggregation_stage(
     cfg: POFLConfig,
     g: jnp.ndarray,
@@ -426,6 +489,7 @@ def aggregation_stage(
     model_shard: ModelShard | None = None,
     stats: aircomp.GradStats | None = None,
     dim: int | None = None,
+    layout: grad_layout.Layout | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Steps 5: transmit + AirComp aggregate per ``cfg.backend`` → (ŷ, e_com).
 
@@ -443,6 +507,11 @@ def aggregation_stage(
     unsharded path; only its placement is sharded) and the returned ŷ is
     still padded (slice ``[:dim]`` at the caller). ``e_com``'s closed form
     always uses the true ``dim``.
+
+    ``layout`` (from :func:`lane_dense_layout`: ``pallas_fused``,
+    unsharded) takes ``g`` as that layout's
+    :class:`~repro.core.grad_layout.GradBlock` with its precomputed
+    ``stats``; ŷ comes back in canonical order.
     """
     backend = AggregationBackend(cfg.backend)
     if model_shard is None:
@@ -454,16 +523,22 @@ def aggregation_stage(
 
         from repro.kernels.aircomp import aircomp_aggregate_fused  # late: kernels↔core
 
-        stats = aircomp.local_stats(g)
+        if layout is None:
+            stats = aircomp.local_stats(g)
+            dim = g.shape[-1]
+        else:
+            dim = layout.dim
         m_g, v_g = aircomp.global_stats(stats, rho, mask)
         h_abs = jnp.abs(h)
         a = aircomp.denoise_scalar(rho, h_abs, mask, cfg.tx_power)
-        dim = g.shape[-1]
         z = jax.random.normal(k_noise, (dim,)) * jnp.sqrt(noise_power)
         coeff = mask * rho  # b_i h_i = ρ_i a exactly (Lemma-1 channel inversion)
-        y_hat = aircomp_aggregate_fused(
-            g, coeff, m_g, v_g, a, z, use_pallas=use_pallas
-        )
+        if layout is None:
+            y_hat = aircomp_aggregate_fused(
+                g, coeff, m_g, v_g, a, z, use_pallas=use_pallas
+            )
+        else:
+            y_hat = _lane_dense_combine(layout, g, coeff, m_g, v_g, a, z, use_pallas)
         e_com = aircomp.distortion_closed_form(
             v_g, rho, h_abs, mask, dim, cfg.tx_power, noise_power
         )
@@ -607,14 +682,25 @@ def round_algorithm(
                 policy_id == scheduling.NOISEFREE_ID, 0.0, noise_power
             )
 
+    # the lane-dense carry (core.grad_layout) where the kernel aggregates
+    # the one-gradient round; None keeps the flat (N, D) block
+    layout = lane_dense_layout(
+        cfg, params, model_shard, traced_algorithm=algorithm_id is not None
+    )
+
     # -- step 2: local updates (K SGD steps per device → delta) -------
     with jax.named_scope("local_update"):
         alg_state_in = alg_state  # pre-round state (the quarantine hold value)
-        g, alg_state = local_update_stage(
-            loss_fn, data, cfg, params, k_batch, t,
-            alg_state=alg_state, algorithm_id=algorithm_id,
-        )  # (N, D) — the legacy single gradient when fedavg/local_steps=1
-        dim = g.shape[-1]
+        if layout is not None:
+            # stateless one-gradient round: alg_state passes through
+            g = local_gradient_block(loss_fn, data, cfg, params, k_batch, layout)
+            dim = layout.dim
+        else:
+            g, alg_state = local_update_stage(
+                loss_fn, data, cfg, params, k_batch, t,
+                alg_state=alg_state, algorithm_id=algorithm_id,
+            )  # (N, D) — the legacy single gradient when fedavg/local_steps=1
+            dim = g.shape[-1]
 
     # -- step 3: uploaded scalar statistics ---------------------------
     with jax.named_scope("statistics"):
@@ -623,6 +709,8 @@ def round_algorithm(
             # masked shard-local reductions + small psums over the model axis
             g = model_shard.pad_features(g, dim)
             stats = _model_sharded_local_stats(model_shard, g, dim)
+        elif layout is not None:
+            stats = grad_layout.block_stats(g, dim)
         else:
             stats = aircomp.local_stats(g)
 
@@ -639,7 +727,7 @@ def round_algorithm(
     with jax.named_scope("aggregation"):
         y_hat, e_com = aggregation_stage(
             cfg, g, rho, h, mask, k_noise, agg_noise_power,
-            model_shard=model_shard, stats=stats, dim=dim,
+            model_shard=model_shard, stats=stats, dim=dim, layout=layout,
         )
         if model_shard is not None:
             # ŷ comes back padded (its tail is sqrt(V_g)/a·0 + M_g, not zero) —
@@ -655,10 +743,13 @@ def round_algorithm(
                 y_hat,
             )
     with jax.named_scope("round_record"):
-        # e_var on the padded g is exact: padded columns are zero in every term
-        e_var = scheduling.global_update_variance(
-            g, rho, mask, data_frac, cfg.n_scheduled
-        )
+        if layout is not None:
+            e_var = grad_layout.block_update_variance(g, rho, mask, data_frac)
+        else:
+            # e_var on the padded g is exact: padded columns are zero in every term
+            e_var = scheduling.global_update_variance(
+                g, rho, mask, data_frac, cfg.n_scheduled
+            )
 
     with jax.named_scope("apply_update"):
         new_params = apply_update_stage(cfg, params, y_hat, t, model_shard=model_shard)

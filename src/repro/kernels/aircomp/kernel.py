@@ -19,6 +19,11 @@ TPU layout notes:
     device reduction is a VPU cross-sublane sum.
   * scalars (M_g, V_g, a, W) ride in SMEM as one (1, 4) block, which
     stays a legal block shape when the lattice vmaps the call.
+  * a lane-dense segment ``(N, rows, L)`` (``core.grad_layout``: a narrow
+    weight's gradient as its product writes it) is read as it lies: each
+    block holds every device and row and the full L when that fits
+    ``_ROWS_BLOCK_BYTES``, so nothing is padded and a cell is one grid
+    step; the device sum adds whole ``(rows, T)`` slabs.
 """
 from __future__ import annotations
 
@@ -35,6 +40,11 @@ DEFAULT_TILE_D = 512
 
 # TPU lane width: tiles stay multiples of this when clamping
 _LANE = 128
+# TPU sublane count of a float32 tile
+_SUBLANE = 8
+# VMEM budget of one (N, rows, tile) gradient block of the lane-dense form;
+# double-buffered it stays far inside the default scoped VMEM
+_ROWS_BLOCK_BYTES = 2 << 20
 
 
 def _clamp_tile(d: int, tile_d: int) -> int:
@@ -63,19 +73,71 @@ def _aircomp_kernel(scalars_ref, coeff_ref, g_ref, z_ref, out_ref):
     out_ref[...] = (acc - w * m_g + (sqrt_vg / a) * z + m_g).astype(out_ref.dtype)
 
 
+def _aircomp_rows_kernel(scalars_ref, coeff_ref, g_ref, z_ref, out_ref):
+    """:func:`_aircomp_kernel` over an ``(N, rows, T)`` block: the same
+    arithmetic per element, the device sum over the block's major axis
+    (``coeff`` rides in SMEM, one scalar per device slab)."""
+    m_g = scalars_ref[0, 0]
+    v_g = scalars_ref[0, 1]
+    a = scalars_ref[0, 2]
+    w = scalars_ref[0, 3]
+
+    z = z_ref[...].astype(jnp.float32)  # (R, T)
+
+    def add_device(i, acc):
+        return acc + coeff_ref[0, i] * g_ref[i].astype(jnp.float32)
+
+    sqrt_vg = jax.lax.sqrt(eps_guard(v_g))
+    acc = jax.lax.fori_loop(
+        0, g_ref.shape[0], add_device, jnp.zeros(z.shape, jnp.float32), unroll=True
+    )  # (R, T)
+    out_ref[...] = (acc - w * m_g + (sqrt_vg / a) * z + m_g).astype(out_ref.dtype)
+
+
+def _rows_tile(n: int, rows: int, length: int) -> int:
+    """Lane tile of an ``(n, rows, length)`` segment: the full length when
+    the tile-padded block fits ``_ROWS_BLOCK_BYTES``, else the widest
+    multiple of 128 lanes that does (the grid then ends in a ragged tile)."""
+    sub = -(-rows // _SUBLANE) * _SUBLANE
+    per_lane_tile = n * sub * _LANE * 4
+    if -(-length // _LANE) * per_lane_tile <= _ROWS_BLOCK_BYTES:
+        return length
+    return max(1, _ROWS_BLOCK_BYTES // per_lane_tile) * _LANE
+
+
+def _aircomp_rows(g, coeff, scalars, z, interpret):
+    n, rows, length = g.shape
+    tile = _rows_tile(n, rows, length)
+    return pl.pallas_call(
+        _aircomp_rows_kernel,
+        grid=(pl.cdiv(length, tile),),
+        in_specs=[
+            pl.BlockSpec((1, 4), lambda i: (0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, n), lambda i: (0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((n, rows, tile), lambda i: (0, 0, i)),  # gradient block
+            pl.BlockSpec((rows, tile), lambda i: (0, i)),        # noise block
+        ],
+        out_specs=pl.BlockSpec((rows, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((rows, length), g.dtype),
+        interpret=interpret,
+    )(scalars, coeff[None, :], g, z)
+
+
 @functools.partial(jax.jit, static_argnames=("tile_d", "interpret"))
 def aircomp_fused(
-    g: jnp.ndarray,       # (n_devices, D)
+    g: jnp.ndarray,       # (n_devices, D), or (n_devices, rows, L)
     coeff: jnp.ndarray,   # (n_devices,)  mask_i · ρ_i
     m_g: jnp.ndarray,     # scalar
     v_g: jnp.ndarray,     # scalar
     a: jnp.ndarray,       # scalar
-    z: jnp.ndarray,       # (D,)
+    z: jnp.ndarray,       # (D,), or (rows, L)
     *,
     tile_d: int = DEFAULT_TILE_D,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused Eq. 5→8 aggregation. Returns ŷ of shape (D,).
+    """Fused Eq. 5→8 aggregation. Returns ŷ of shape (D,), or (rows, L)
+    for a lane-dense ``(n_devices, rows, L)`` segment, read unpadded
+    (``tile_d`` applies to the flat form only).
 
     D is padded to a multiple of ``tile_d`` internally; a ``tile_d`` wider
     than (128-lane-aligned) D is clamped first, so shard-local blocks of a
@@ -89,17 +151,19 @@ def aircomp_fused(
     whole ``(4,)`` vector, whose batched ``(cells, 4)`` form the TPU
     lowering refuses.
     """
+    scalars = jnp.stack(
+        [m_g.astype(jnp.float32), v_g.astype(jnp.float32),
+         a.astype(jnp.float32), jnp.sum(coeff).astype(jnp.float32)]
+    )[None, :]
+    if g.ndim == 3:
+        return _aircomp_rows(g, coeff, scalars, z, interpret)
+
     n, d = g.shape
     tile_d = _clamp_tile(d, tile_d)
     d_pad = ((d + tile_d - 1) // tile_d) * tile_d
     if d_pad != d:
         g = jnp.pad(g, ((0, 0), (0, d_pad - d)))
         z = jnp.pad(z, (0, d_pad - d))
-
-    scalars = jnp.stack(
-        [m_g.astype(jnp.float32), v_g.astype(jnp.float32),
-         a.astype(jnp.float32), jnp.sum(coeff).astype(jnp.float32)]
-    )[None, :]
 
     out = pl.pallas_call(
         _aircomp_kernel,

@@ -38,7 +38,7 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _resolve(use_pallas: str | bool) -> str | bool:
+def resolve_mode(use_pallas: str | bool) -> str | bool:
     """Normalize a ``use_pallas`` argument to True / False / 'interpret'."""
     if use_pallas == "auto":
         if _on_tpu():
@@ -51,7 +51,7 @@ def aircomp_aggregate_fused(
     g, coeff, m_g, v_g, a, z, *, use_pallas: str | bool = "auto", tile_d: int = DEFAULT_TILE_D
 ):
     """Fused Eq. 5→8: ŷ = Σ_i coeff_i·(g_i − M_g) + sqrt(V_g)/a·z + M_g."""
-    mode = _resolve(use_pallas)
+    mode = resolve_mode(use_pallas)
     if mode == "interpret":
         return aircomp_fused(g, coeff, m_g, v_g, a, z, tile_d=tile_d, interpret=True)
     if mode:
@@ -63,7 +63,7 @@ def aircomp_aggregate_fused_batch(
     g, coeff, m_g, v_g, a, z, *, use_pallas: str | bool = "auto", tile_d: int = DEFAULT_TILE_D
 ):
     """Trial-batched fused Eq. 5→8 over (n_trials, n_devices, D) gradients."""
-    mode = _resolve(use_pallas)
+    mode = resolve_mode(use_pallas)
     if mode == "interpret":
         return aircomp_fused_batch(g, coeff, m_g, v_g, a, z, tile_d=tile_d, interpret=True)
     if mode:

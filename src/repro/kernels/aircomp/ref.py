@@ -30,11 +30,12 @@ def aircomp_fused_batch_ref(g, coeff, m_g, v_g, a, z):
 
 def aircomp_fused_ref(g, coeff, m_g, v_g, a, z):
     """Args:
-      g:     (n_devices, D) stacked local gradients
+      g:     (n_devices, D) stacked local gradients, or a lane-dense
+             (n_devices, rows, L) segment
       coeff: (n_devices,)   mask_i · ρ_i
       m_g, v_g, a: scalars  (global mean/variance, denoise scalar)
-      z:     (D,)           receiver noise ~ N(0, σ_z²)
-    Returns ŷ: (D,)
+      z:     (D,)           receiver noise ~ N(0, σ_z²), or (rows, L)
+    Returns ŷ: (D,), or (rows, L)
 
     ``a`` is cancelled algebraically in the signal term — exactly as the
     Pallas kernel does — so an empty scheduled set (a=inf from the min over
@@ -42,6 +43,6 @@ def aircomp_fused_ref(g, coeff, m_g, v_g, a, z):
     would produce 0·inf = NaN there.
     """
     sqrt_vg = jnp.sqrt(eps_guard(v_g))
-    acc = jnp.sum(coeff[:, None] * g, axis=0)    # Eq. 7 signal, a cancelled
+    acc = jnp.sum(coeff.reshape((-1,) + (1,) * (g.ndim - 1)) * g, axis=0)  # Eq. 7, a cancelled
     w = jnp.sum(coeff)
     return acc - w * m_g + sqrt_vg / a * z + m_g  # Eq. 8

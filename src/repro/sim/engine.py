@@ -51,10 +51,11 @@ from repro.core import local_update
 from repro.core.channel import ChannelConfig
 from repro.core.metrics import RoundDiagnostics, zero_round_health
 from repro.core.pofl import (
-    DeviceData, History, ModelShard, POFLConfig, round_algorithm,
+    DeviceData, History, ModelShard, POFLConfig, lane_dense_layout,
+    round_algorithm,
 )
 from repro.obs.config import DEFAULT_OBS, ObsConfig
-from repro.obs.registry import counter_add, metric_value, reset_metrics
+from repro.obs.registry import counter_add, gauge_set, metric_value, reset_metrics
 from repro.obs.spans import span
 from repro.obs.stages import register_module
 from repro.sim.compile_cache import install_listener
@@ -619,9 +620,11 @@ class SimEngine:
         executable also exposes XLA's per-program ``cost_analysis`` /
         ``memory_analysis`` (see :meth:`lattice_cost_analysis`). Each compile
         adds 0 or 1 to ``lattice.cache_misses`` (1 when it missed JAX's
-        persistent cache) and registers its modules with
-        ``repro.obs.stages``, so a profiler trace of the program can be read
-        stage by stage.
+        persistent cache), sets the gauges ``lattice.lane_dense_leaves`` and
+        ``lattice.lane_dense_elems`` (the gradient leaves and elements its
+        rounds carry lane-dense, ``core.grad_layout``) and registers its
+        modules with ``repro.obs.stages``, so a profiler trace of the
+        program can be read stage by stage.
         """
         leaves, treedef = jax.tree.flatten(args)
         # mesh identity rides at the END of the key (append-only contract):
@@ -658,6 +661,21 @@ class SimEngine:
                 "lattice.cache_misses",
                 int(metric_value("compile_cache.misses") > misses0),
             )
+            if mode not in ("init", "init_alg"):
+                # how much of the gradient block this program carries
+                # lane-dense (core.grad_layout); 0 for the flat block
+                params = args[0]
+                if mode in ("chunk", "chunk_alg"):  # the carry, batched over cells
+                    params = jax.tree.map(
+                        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                        params.params,
+                    )
+                layout = lane_dense_layout(
+                    self.cfg, params, self._model_shard,
+                    traced_algorithm=mode in ("fused_alg", "chunk_alg"),
+                )
+                gauge_set("lattice.lane_dense_leaves", layout.n_dense if layout else 0)
+                gauge_set("lattice.lane_dense_elems", layout.dense_elems if layout else 0)
             # the op -> round-stage map of this program, for trace readers
             # (``repro.obs.stages``; rendered only when first read)
             for module in compiled.runtime_executable().hlo_modules():

@@ -13,7 +13,7 @@ from repro.kernels.aircomp import (
     aircomp_fused_batch_ref,
     aircomp_fused_ref,
 )
-from repro.kernels.aircomp.kernel import DEFAULT_TILE_D, _clamp_tile
+from repro.kernels.aircomp.kernel import DEFAULT_TILE_D, _clamp_tile, _rows_tile
 from repro.kernels.attention import flash_attention, mha_ref
 from repro.kernels.ssd import ssd_chunked_ref, ssd_naive, ssd_pallas
 
@@ -112,6 +112,62 @@ def test_aircomp_fused_zero_noise_is_weighted_sum():
         jnp.zeros((512,)), interpret=True,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(g.mean(0)), rtol=1e-5, atol=1e-6)
+
+
+# the lane-dense (n_devices, rows, L) form: logreg's carried weight, a small
+# one, L off the lane grid, and one long enough to tile with a ragged end
+_ROWS_SHAPES = [(30, 10, 784), (6, 10, 128), (5, 3, 300), (7, 10, 5000)]
+
+
+@pytest.mark.parametrize("n,rows,length", _ROWS_SHAPES)
+def test_aircomp_fused_rows_matches_ref(n, rows, length):
+    ks = jax.random.split(jax.random.PRNGKey(n * length + rows), 4)
+    g = jax.random.normal(ks[0], (n, rows, length))
+    coeff = jax.random.uniform(ks[1], (n,)) * (
+        jax.random.uniform(ks[2], (n,)) > 0.3
+    )
+    z = jax.random.normal(ks[3], (rows, length))
+    m_g, v_g, a = jnp.float32(0.21), jnp.float32(0.9), jnp.float32(1.7)
+
+    got = aircomp_fused(g, coeff, m_g, v_g, a, z, interpret=True)
+    want = aircomp_fused_ref(g, coeff, m_g, v_g, a, z)
+    assert got.shape == (rows, length)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    # the same numbers as the flat form over the same device rows
+    flat = aircomp_fused(
+        g.reshape(n, -1), coeff, m_g, v_g, a, z.reshape(-1), interpret=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(got).reshape(-1), np.asarray(flat), rtol=1e-5, atol=1e-5
+    )
+
+
+def test_aircomp_fused_rows_batched_as_the_lattice_batches():
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    bt, n, rows, length = 3, 6, 10, 784
+    g = jax.random.normal(ks[0], (bt, n, rows, length))
+    coeff = jax.random.uniform(ks[1], (bt, n)) * (
+        jax.random.uniform(ks[2], (bt, n)) > 0.3
+    )
+    z = jax.random.normal(ks[3], (bt, rows, length))
+    m_g = jax.random.normal(ks[4], (bt,)) * 0.1
+    v_g = jax.random.uniform(ks[5], (bt,)) + 0.5
+    a = jnp.full((bt,), 2.0)
+
+    got = aircomp_fused_batch(g, coeff, m_g, v_g, a, z, interpret=True)
+    want = aircomp_fused_batch_ref(g, coeff, m_g, v_g, a, z)
+    assert got.shape == (bt, rows, length)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_rows_tile_rule():
+    # a whole logreg cell (30 x 16 x 896 floats, 1.7 MB) is one block...
+    assert _rows_tile(30, 10, 784) == 784
+    assert _rows_tile(6, 3, 300) == 300
+    # ...a longer segment tiles in whole lanes under the block budget
+    assert _rows_tile(30, 10, 100_000) == 1024
+    assert _rows_tile(7, 10, 5000) == 4608  # two tiles, the second ragged
+    assert _rows_tile(300, 100, 100_000) == 128
 
 
 # --------------------------------------------------------------------------

@@ -487,17 +487,17 @@ def test_interpret_env_var_dispatch_and_cache_keying(setup, monkeypatch):
     """REPRO_PALLAS_INTERPRET flips the 'auto' dispatch to interpret mode at
     trace time, and cached_engine keys on it so a flipped var can never
     replay a stale-mode trace."""
-    from repro.kernels.aircomp.ops import _resolve
+    from repro.kernels.aircomp.ops import resolve_mode
 
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    assert _resolve("auto") in (True, False)  # plain hardware dispatch
-    assert _resolve(False) is False and _resolve("interpret") == "interpret"
+    assert resolve_mode("auto") in (True, False)  # plain hardware dispatch
+    assert resolve_mode(False) is False and resolve_mode("interpret") == "interpret"
     data, _, _ = setup
     cfg = POFLConfig(n_devices=12, n_scheduled=4, backend="pallas_fused")
     eng_plain = cached_engine(_loss_fn, data, cfg)
 
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert _resolve("auto") == "interpret"
+    assert resolve_mode("auto") == "interpret"
     assert cached_engine(_loss_fn, data, cfg) is not eng_plain
 
 

@@ -78,25 +78,19 @@ def test_aircomp_compiles_for_v5e(op, shape, one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_lattice_stages_named_in_the_v5e_program(one_chip, no_compile_cache, monkeypatch):
-    """A small fused lattice (8 cells, N = 30, D = 7,850) compiled for the
-    described chip: the kernel keeps the name the benchmark's kernel
-    metrics find it by and sits in the ``aggregation`` stage, and the
-    mini-batch gather, the gradient product and the gradient block's
-    relayout copies belong to ``local_update``."""
-    import re
-
+def _logreg_lattice_hlo(one_chip, monkeypatch, cells: int) -> str:
+    """The optimized HLO of a fused logreg lattice (``cells`` cells, N = 30,
+    D = 7,850, 3 rounds) compiled for the described chip."""
     import numpy as np
 
     import repro.kernels.aircomp.ops as ops
     from repro.core.pofl import DeviceData, POFLConfig
     from repro.models import small
-    from repro.obs.stages import build_stage_map
     from repro.sim.engine import FUSED_POLICY, cached_engine
     from repro.sim.tasks import TaskEval
 
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    cells, n, n_samples, rounds = 8, 30, 20, 3
+    n, n_samples, rounds = 30, 20, 3
     rng = np.random.default_rng(0)
     fx = jnp.asarray(rng.standard_normal((n, n_samples, 784)), jnp.float32)
     fy = jnp.asarray(rng.integers(0, 10, (n, n_samples)), jnp.int32)
@@ -112,23 +106,54 @@ def test_lattice_stages_named_in_the_v5e_program(one_chip, no_compile_cache, mon
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    text = eng._fused_lattice_jit.lower(
+    return eng._fused_lattice_jit.lower(
         {"w": sds((784, 10)), "b": sds((10,))}, sds((rounds,), jnp.int32),
         sds((rounds,), jnp.bool_), sds((cells,)), sds((cells,)),
         sds((cells,), jnp.int32), sds((cells,), jnp.int32),
     ).compile().as_text()
-    table = build_stage_map(text)
 
-    bodies, current, lines = {}, None, {}
+
+def _hlo_instructions(text: str):
+    """(computation -> its instruction lines, instruction -> its text after
+    ``=``, instruction -> its computation)."""
+    import re
+
+    bodies, current, lines, where = {}, None, {}, {}
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY\s+)?%([^\s(]+)\s*\(.*\{\s*$", line)
         if head:
             current = bodies.setdefault(head.group(1), [])
+            name = head.group(1)
             continue
         m = re.match(r"^\s*(?:ROOT\s+)?%([^\s=]+)\s*=\s*(.*)$", line)
         if m and current is not None:
             current.append(line)
             lines[m.group(1)] = m.group(2)
+            where[m.group(1)] = name
+    return bodies, lines, where
+
+
+def _output_dims(rest: str, opcode: str) -> list:
+    """The dimensions of each array an ``opcode`` instruction outputs."""
+    import re
+
+    if f" {opcode}(" not in rest:
+        return []
+    return re.findall(r"\w+\[([\d,]*)\]", rest.split(f" {opcode}(")[0])
+
+
+def test_lattice_stages_named_in_the_v5e_program(one_chip, no_compile_cache, monkeypatch):
+    """A small fused lattice (8 cells, N = 30, D = 7,850) compiled for the
+    described chip: the kernel keeps the name the benchmark's kernel
+    metrics find it by and sits in the ``aggregation`` stage, and the
+    mini-batch gather and the gradient product belong to ``local_update``."""
+    import re
+
+    from repro.obs.stages import build_stage_map
+
+    text = _logreg_lattice_hlo(one_chip, monkeypatch, cells=8)
+    table = build_stage_map(text)
+    bodies, lines, _ = _hlo_instructions(text)
 
     def fused(rest):
         called = re.search(r"calls=%([^\s,}]+)", rest)
@@ -139,19 +164,40 @@ def test_lattice_stages_named_in_the_v5e_program(one_chip, no_compile_cache, mon
     assert "/aggregation/" in re.search(r'op_name="([^"]*)"', lines[kernel]).group(1)
     assert table[kernel] == "aggregation"
 
-    def dims(rest, opcode):
-        """The dimensions of each array an ``opcode`` instruction outputs."""
-        if f" {opcode}(" not in rest:
-            return []
-        return re.findall(r"\w+\[([\d,]*)\]", rest.split(f" {opcode}(")[0])
-
     gathers = [n for n, r in lines.items()
-               if any(d.endswith(",784") for d in dims(r, "fusion")) and "gather(" in fused(r)]
+               if any(d.endswith(",784") for d in _output_dims(r, "fusion"))
+               and "gather(" in fused(r)]
     dots = [n for n, r in lines.items()
-            if any(d.endswith(",784,10") for d in dims(r, "fusion"))
+            if any(re.search(r",(784,10|10,784)$", d) for d in _output_dims(r, "fusion"))
             and "convolution(" in fused(r)]
-    relayouts = [n for n, r in lines.items()
-                 if any(re.fullmatch(r"\d+,30,(784,10|7840)", d) for d in dims(r, "copy"))]
-    assert gathers and dots and relayouts
-    for name in gathers + dots + relayouts:
+    assert gathers and dots
+    for name in gathers + dots:
         assert table[name] == "local_update", (name, lines[name][:200])
+
+
+def test_gradient_block_written_once_on_v5e(one_chip, no_compile_cache, monkeypatch):
+    """The cell-shaped round (15 cells, N = 30, D = 7,850) compiled for the
+    described chip carries the weight gradient lane-dense: the kernel
+    aggregates it, and no copy under ``local_update`` nor pad under
+    ``aggregation`` writes a block of cells x N x 7,840 elements or more
+    between the product and its readers."""
+    import math
+    import re
+
+    cells, n = 15, 30
+    text = _logreg_lattice_hlo(one_chip, monkeypatch, cells=cells)
+    assert "tpu_custom_call" in text
+    _, lines, where = _hlo_instructions(text)
+    fused = {c for r in lines.values() for c in re.findall(r"calls=%([^\s,}]+)", r)}
+    big = []
+    for name, rest in lines.items():
+        if where[name] in fused:  # a fusion's body: its fusion is the op
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        op_name = op_name.group(1) if op_name else ""
+        for opcode, stage in (("copy", "/local_update/"), ("pad", "/aggregation/")):
+            sizes = [math.prod(int(x) for x in d.split(",") if x)
+                     for d in _output_dims(rest, opcode)]
+            if stage in op_name and any(s >= cells * n * 7840 for s in sizes):
+                big.append((name, rest[:160]))
+    assert not big, big
