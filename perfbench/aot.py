@@ -1,4 +1,4 @@
-"""Compile a cell's lattice program for a described TPU v5e, with no chip.
+"""Compile a lattice cell's program for a described TPU v5e, with no chip.
 
     JAX_PLATFORMS=cpu python3 -m perfbench.aot --workload <name>
 
@@ -29,7 +29,8 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-    from perfbench.run import ROOT, Program, cell_grid, load_cell, sweep_seeds
+    from perfbench.programs.lattice import Program, cell_grid
+    from perfbench.run import ROOT, load_cell, sweep_seeds
 
     sys.path.insert(0, os.path.join(ROOT, "src"))
     import repro.kernels.aircomp.ops as ops
@@ -37,8 +38,12 @@ def main(argv=None) -> int:
 
     from perfbench import data as bdata
 
-    ops._on_tpu = lambda: True
     _, cell, config, traffic = load_cell(ROOT, args.workload)
+    if config.get("program", "lattice") != "lattice":
+        print(f"aot: {args.workload} runs program {config['program']!r}; this "
+              "tool compiles only the lattice's", file=sys.stderr)
+        return 2
+    ops._on_tpu = lambda: True
     chips = cell["chips"]
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     data = bdata.make_dataset(config)

@@ -32,16 +32,14 @@ from types import SimpleNamespace
 import numpy as np
 
 from perfbench import check
-from perfbench.run import (
-    CHECK_ROUNDS, ROOT, Program, cell_grid, enable_compile_cache, first_rounds,
-    load_cell, seed32, sweep_seeds,
-)
+from perfbench.programs.lattice import CHECK_ROUNDS, Program, cell_grid, first_rounds
+from perfbench.run import ROOT, enable_compile_cache, load_cell, seed32, sweep_seeds
 
 MARGINS = (0.0, 1e-5, 1e-4, 1e-3, 1e-2)
 
 
 class ReferenceProgram:
-    """A :class:`perfbench.run.Program` whose sweeps are the reference's
+    """A lattice :class:`perfbench.programs.lattice.Program` whose sweeps are the reference's
     first rounds: with ``dtype=bfloat16`` the control, with ``fault`` a
     planted fault."""
 
@@ -98,6 +96,10 @@ def main(argv=None, *, root: str = ROOT, require_tpu: bool = True) -> int:
     import jax.numpy as jnp
 
     _, cell, config, traffic = load_cell(root, args.workload)
+    if config.get("program", "lattice") != "lattice":
+        print(f"calibrate: {args.workload} runs program {config['program']!r}; "
+              "this tool calibrates only the lattice's", file=sys.stderr)
+        return 2
     devices = jax.devices()
     if require_tpu and (devices[0].platform != "tpu" or len(devices) < cell["chips"]):
         print("calibrate: needs the cell's TPU chips", file=sys.stderr)

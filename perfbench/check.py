@@ -1,7 +1,11 @@
 """The comparison that decides ``correct``.
 
-The numbers compared, each against a limit kept with the configuration
-(``limits`` in ``perfbench/configs/<config>.json``):
+:func:`merge` and :func:`verdict` serve every program: they fold the
+readings a program's ``check`` returns, one per sweep, and hold each
+number to its limit. :func:`compare` is the lattice's reading.
+
+The lattice's numbers compared, each against a limit kept with the
+configuration (``limits`` in ``perfbench/configs/<config>.json``):
 
 * ``n_scheduled_mismatch``: cell-rounds whose realized |S^t| differs from
   the reference's. Exact: the limit is 0.

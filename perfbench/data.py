@@ -9,7 +9,7 @@ harness hands these arrays to the program (``DeviceData``, ``TaskEval``,
 
 The dataset comes from the configuration's fixed ``data_seed``; the run's
 ``--seed`` drives only the initial weights and the lattice seeds (see
-``perfbench/run.py`` for why).
+``perfbench/programs/lattice.py`` for why).
 """
 from __future__ import annotations
 
@@ -67,9 +67,22 @@ def noniid_shards(features, labels, n_devices: int, shards_per_device: int,
     return np.stack(feats), np.stack(labs)
 
 
+TASKS = ("logreg", "cnn")  # the lattice's tasks, each with its own data and weights
+
+
+def check_task(config: dict) -> None:
+    """Refuse a ``task`` the lattice does not run, rather than reading it
+    as another task's."""
+    if config.get("task") not in TASKS:
+        raise ValueError(
+            f"unknown lattice task {config.get('task')!r}; known: {list(TASKS)}"
+        )
+
+
 def make_dataset(config: dict):
     """``(train_x (N, m, ...), train_y (N, m), test_x, test_y)`` as numpy
     arrays, from the configuration's sizes and ``data_seed``."""
+    check_task(config)
     kind = "mnist_like" if config["task"] == "logreg" else "cifar_like"
     dim = int(np.prod(config["input_shape"]))
     key = jax.random.PRNGKey(config["data_seed"])
@@ -93,6 +106,7 @@ def make_dataset(config: dict):
 def param_shapes(config: dict) -> dict:
     """Parameter shapes by name, the dict-of-dicts layout the program's
     models take (``w``/``b`` per layer)."""
+    check_task(config)
     n_cls = config["n_classes"]
     if config["task"] == "logreg":
         d = int(np.prod(config["input_shape"]))

@@ -1,5 +1,6 @@
-"""Engine: lattice programs compiled inside the measured window, from the
-program's ``lattice.n_compiles`` counter. Should read 0."""
+"""Engine: programs compiled inside the measured window, from the program
+module's ``counters()`` (the lattice's ``lattice.n_compiles``). Should
+read 0; None where the program does not count them."""
 
 
 def read(ctx):

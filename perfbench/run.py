@@ -4,38 +4,55 @@
 
 from the root of a checkout. Everything a cell needs is found by name:
 the cell in ``BENCHMARK.json``, its configuration in the file that entry
-names, its traffic in ``perfbench/traffic/<traffic>.json`` and each
-per-layer metric in ``perfbench/metrics/<metric>.py``.
+names, its traffic in ``perfbench/traffic/<traffic>.json``, the program
+it drives in ``perfbench/programs/<program>.py`` (the configuration's
+``program`` key; ``lattice`` where it has none) and each per-layer metric
+in ``perfbench/metrics/<metric>.py``. So a configuration that needs its
+own program joins the benchmark as new files and entries only.
+
+A program module supplies:
+
+* ``make_data(config)``: the dataset, drawn from the configuration alone;
+* ``init_params(config, key)``: the initial state, from the run's key;
+* ``Program(config, traffic, data, params0)``, whose ``sweep(seeds)`` runs
+  one unit of the timed work and returns its records. ``seeds`` holds the
+  traffic's ``seeds`` distinct run seeds, drawn from ``--seed``;
+* ``n_cells(traffic)``: the cells one sweep runs;
+* ``cell_rounds_per_sweep(traffic)``: the cell-rounds one sweep completes,
+  the unit of ``cell_rounds_per_s``;
+* ``kept(records)``: what the check compares of a sweep's records;
+* ``counters()``: (seconds spent compiling, programs compiled) so far,
+  each None where the program does not count it;
+* ``release()``: frees the program's cached state before the check;
+* ``check(config, traffic, data, params0, kept_list)``: one reading per
+  ``(seeds, kept)`` pair of ``kept_list``, a dict of the numbers that
+  ``perfbench/check.py`` holds to the configuration's ``limits``, with
+  ``cells_compared``, the answers compared (none is not correct);
+* ``flops_per_cell_round(config, traffic)``: the model FLOPs of one
+  cell-round, or None.
 
 One run is one process on the chips it holds. In order it
 
 1. checks for a TPU with as many chips as the cell asks for, and exits 2
-   with no result when there is none (no CPU fallback, no interpret mode);
+   with no result when there is none (no CPU fallback, no interpret mode),
+   and exits 2 when the configuration's program is missing or incomplete;
 2. keeps JAX's compilation cache in ``$JAX_COMPILATION_CACHE_DIR`` when
    set, else in ``<checkout>/.jax_cache``;
-3. draws the configuration's dataset from its fixed ``data_seed``, and the
-   initial weights from ``--seed`` on the device. The program bakes its
-   dataset into the compiled lattice as a constant, so a dataset drawn
-   from ``--seed`` would recompile every run; ``--seed`` drives the
-   weights and every lattice seed (channels, mini-batches, scheduling
-   draws, receiver noise) instead;
-4. compiles the cell's one lattice program and runs one warm-up sweep
-   (set-up ends here);
+3. makes the program's data and, from ``--seed``, its initial state;
+4. builds the program and runs one warm-up sweep (set-up ends here);
 5. runs sweeps back to back until ``--seconds`` have passed; sweep ``k``
-   takes its lattice seeds from ``(--seed, k)``, values the program vmaps,
-   so nothing compiles in the window. With ``--trace 1`` the window runs
-   under the profiler, each sweep inside a ``perfbench.sweep`` span and
-   the harness's loop between sweeps inside ``perfbench.between``;
+   takes its run seeds from ``(--seed, k)``. With ``--trace 1`` the window
+   runs under the profiler, each sweep inside a ``perfbench.sweep`` span
+   and the harness's loop between sweeps inside ``perfbench.between``;
 6. reads the peak device memory, frees the program's state, and checks
-   the first rounds of the warm-up sweep and of every sweep of the window
-   against the plain reference (``perfbench/reference.py``,
-   ``perfbench/check.py``);
+   the kept records of the warm-up sweep and of every sweep of the window
+   with the program's check;
 7. prints each number compared with its limit as the last lines of
    standard error, and one JSON object as the last line of standard
    output, naming the device.
 
-A lattice sweep is a batch job: ``cell_rounds_per_s`` is every cell-round
-of every sweep completed in the window over the window's whole wall time,
+A sweep is a batch job: ``cell_rounds_per_s`` is every cell-round of
+every sweep completed in the window over the window's whole wall time,
 from the first sweep's dispatch to the last sweep's records on the host.
 """
 from __future__ import annotations
@@ -43,7 +60,6 @@ from __future__ import annotations
 import argparse
 import gc
 import importlib.util
-import itertools
 import json
 import os
 import shutil
@@ -55,7 +71,10 @@ import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-CHECK_ROUNDS = 3  # rounds of each sweep the reference follows
+PROGRAM_NAMES = (
+    "make_data", "init_params", "Program", "n_cells", "cell_rounds_per_sweep",
+    "kept", "counters", "release", "check", "flops_per_cell_round",
+)
 
 
 def load_cell(root: str, workload: str):
@@ -83,6 +102,23 @@ def load_metric(root: str, name: str):
     return mod
 
 
+def load_program(root: str, name: str):
+    """The program module ``name``: ``perfbench/programs/<name>.py`` under
+    ``root``. Raises ``LookupError`` naming what is missing."""
+    path = os.path.join(root, "perfbench", "programs", name + ".py")
+    if not os.path.isfile(path):
+        raise LookupError(f"no program {name!r}: {path} does not exist")
+    mod_name = f"perfbench_program_{name}"
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod
+    spec.loader.exec_module(mod)
+    missing = [n for n in PROGRAM_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise LookupError(f"program {name!r} ({path}) lacks {', '.join(missing)}")
+    return mod
+
+
 def seed32(*entropy) -> int:
     """A non-negative int32 drawn from any whole numbers (``--seed`` may
     exceed 32 bits)."""
@@ -90,77 +126,12 @@ def seed32(*entropy) -> int:
 
 
 def sweep_seeds(seed: int, k: int, n: int) -> tuple:
-    """``n`` distinct lattice seeds of sweep ``k``."""
+    """``n`` distinct run seeds of sweep ``k``."""
     state = np.random.SeedSequence([seed, k]).generate_state(4 * n) & 0x7FFFFFFF
     out = list(dict.fromkeys(int(s) for s in state))[:n]
     if len(out) < n:
         raise RuntimeError("sweep seeds collided")
     return tuple(out)
-
-
-def cell_grid(traffic: dict, seeds: tuple) -> list:
-    """(policy, noise power, alpha, seed) of each cell, in the lattice's
-    flat order: policy-major, then noise, alpha, seed."""
-    return list(itertools.product(
-        traffic["policies"], traffic["noise_powers"], traffic["alphas"], seeds
-    ))
-
-
-class Program:
-    """The system under test: the lattice of the cell, as users drive it."""
-
-    def __init__(self, config: dict, traffic: dict, data, params0):
-        import jax.numpy as jnp
-
-        from repro.core.pofl import DeviceData, POFLConfig
-        from repro.models import small
-        from repro.sim import LatticeSpec, run_lattice
-        from repro.sim.tasks import TaskEval
-
-        fx, fy, x_te, y_te = data
-        self._spec_cls = LatticeSpec
-        self._run = run_lattice
-        if config["task"] == "logreg":
-            self.loss_fn, logits_fn = small.logreg_loss, small.logreg_logits
-        else:
-            self.loss_fn, logits_fn = small.cnn_loss, small.cnn_logits
-        self.data = DeviceData(features=jnp.asarray(fx), labels=jnp.asarray(fy))
-        self.eval_fn = TaskEval(logits_fn, x_te, y_te, batch=config["n_test"])
-        self.cfg = POFLConfig(
-            n_devices=config["n_devices"], n_scheduled=config["n_scheduled"],
-            batch_size=config["batch_size"], local_steps=config["local_steps"],
-            lr0=config["lr0"], lr_decay=config["lr_decay"], lr_min=config["lr_min"],
-            tx_power=config["tx_power"], sampler=config["sampler"],
-            simulate_physical=True, backend="pallas_fused",
-        )
-        self.traffic = traffic
-        self.params0 = params0
-        self.mesh = tuple(traffic["mesh"]) if traffic.get("mesh") else None
-
-    def sweep(self, seeds: tuple):
-        t = self.traffic
-        spec = self._spec_cls(
-            policies=tuple(t["policies"]), noise_powers=tuple(t["noise_powers"]),
-            alphas=tuple(t["alphas"]), seeds=seeds, n_rounds=t["rounds"],
-            eval_every=t["eval_every"],
-        )
-        return self._run(
-            self.loss_fn, self.data, self.params0, spec, base_cfg=self.cfg,
-            eval_fn=self.eval_fn, mesh=self.mesh,
-        )
-
-
-def first_rounds(recs) -> dict:
-    """The records the check compares, flat over cells (lattice order)."""
-    t = CHECK_ROUNDS
-    out = {
-        f: np.asarray(getattr(recs, f)).reshape(-1, np.shape(getattr(recs, f))[-1])[:, :t]
-        for f in ("grad_norm", "e_com", "e_var", "n_scheduled")
-    }
-    for f in ("loss", "acc"):
-        v = np.asarray(getattr(recs.eval, f))
-        out[f"eval0_{f}"] = v.reshape(-1, v.shape[-1])[:, 0]
-    return out
 
 
 def enable_compile_cache(root: str) -> str:
@@ -179,10 +150,11 @@ def log(msg: str) -> None:
 
 
 def main(argv=None, *, t0: float | None = None, root: str = ROOT,
-         require_tpu: bool = True, program_cls=Program) -> int:
-    """One run. ``require_tpu=False`` and ``program_cls`` exist for the
-    harness's own tests, which drive a run on the CPU at a tiny size, with
-    the program broken underneath or the control in its place."""
+         require_tpu: bool = True, program_cls=None) -> int:
+    """One run. ``require_tpu=False`` and ``program_cls`` (in place of the
+    program module's ``Program``) exist for the harness's own tests, which
+    drive a run on the CPU at a tiny size, with the program broken
+    underneath or the control in its place."""
     t0 = time.perf_counter() if t0 is None else t0
     ap = argparse.ArgumentParser(description="Run one perfbench cell once.")
     ap.add_argument("--workload", required=True)
@@ -197,6 +169,11 @@ def main(argv=None, *, t0: float | None = None, root: str = ROOT,
         return 2
     bench, cell, config, traffic = load_cell(root, args.workload)
     chips = int(cell["chips"])
+    try:
+        program = load_program(root, config.get("program", "lattice"))
+    except LookupError as e:
+        print(f"perfbench: {e}", file=sys.stderr)
+        return 2
 
     import jax
 
@@ -211,26 +188,24 @@ def main(argv=None, *, t0: float | None = None, root: str = ROOT,
     cache = enable_compile_cache(root)
     if src not in sys.path:
         sys.path.insert(0, src)
-    from repro.obs.registry import metric_value
-
-    from perfbench import check, data as bdata
+    from perfbench import check
 
     t_data = time.perf_counter()
-    data = bdata.make_dataset(config)
-    params0 = bdata.init_params(config, jax.random.PRNGKey(seed32(args.seed, 1 << 40)))
-    prog = program_cls(config, traffic, data, params0)
+    data = program.make_data(config)
+    params0 = program.init_params(config, jax.random.PRNGKey(seed32(args.seed, 1 << 40)))
+    prog = (program_cls or program.Program)(config, traffic, data, params0)
     seeds = sweep_seeds(args.seed, 0, traffic["seeds"])
-    n_cells = len(cell_grid(traffic, seeds))
-    kept = []  # (seeds, first-round records) of every sweep run
+    n_cells = program.n_cells(traffic)
+    kept = []  # (seeds, kept records) of every sweep run
     t_warm = time.perf_counter()
-    kept.append((seeds, first_rounds(prog.sweep(seeds))))  # warm-up sweep
+    kept.append((seeds, program.kept(prog.sweep(seeds))))  # warm-up sweep
     setup_s = time.perf_counter() - t0
-    compile_s = float(metric_value("lattice.compile_seconds"))
-    compiles0 = int(metric_value("lattice.n_compiles"))
+    compile_s, compiles0 = program.counters()
     log(f"device {devices[0].device_kind} x{len(devices)}; cache {cache}; "
         f"set-up {setup_s:.3f} s: start to data {t_data - t0:.3f}, data and "
         f"weights {t_warm - t_data:.3f}, warm-up sweep {time.perf_counter() - t_warm:.3f} "
-        f"(of it compile or cache load {compile_s:.3f})")
+        f"(of it compile or cache load "
+        f"{'not counted' if compile_s is None else f'{compile_s:.3f}'})")
 
     trace_dir = os.path.join(root, "chiprun_out", "perfbench", args.workload, "trace")
     if args.trace:
@@ -246,7 +221,7 @@ def main(argv=None, *, t0: float | None = None, root: str = ROOT,
         ends.append(time.perf_counter())
         window_s = ends[-1] - t_start
         with jax.profiler.TraceAnnotation("perfbench.between"):
-            kept.append((seeds, first_rounds(recs)))
+            kept.append((seeds, program.kept(recs)))
             k += 1
             seeds = sweep_seeds(args.seed, k, traffic["seeds"])
         if window_s >= args.seconds:
@@ -254,8 +229,9 @@ def main(argv=None, *, t0: float | None = None, root: str = ROOT,
     n_sweeps = k - 1
     if args.trace:
         jax.profiler.stop_trace()
-    window_compiles = int(metric_value("lattice.n_compiles")) - compiles0
-    rate = n_cells * traffic["rounds"] * n_sweeps / window_s
+    compiles1 = program.counters()[1]
+    window_compiles = None if compiles0 is None else compiles1 - compiles0
+    rate = program.cell_rounds_per_sweep(traffic) * n_sweeps / window_s
     # the TPU runtime reserves an executable's temporaries apart from the
     # buffers it allocates, so a chip's peak is the sum of the two peaks
     stats = [d.memory_stats() or {} for d in devices]
@@ -267,33 +243,18 @@ def main(argv=None, *, t0: float | None = None, root: str = ROOT,
         f"{[round(b - a, 3) for a, b in zip(ends, ends[1:])]} s")
 
     # free the program's state before the reference takes the chip
-    from perfbench.reference import POLICIES, Reference, flatten, layout
-
-    lay = layout(bdata.param_shapes(config))
-    w0_flat = flatten(params0, lay)
-    del prog, recs, params0
-    from repro.sim import reset_engine_cache
-
-    reset_engine_cache()
+    del prog, recs
+    program.release()
     gc.collect()
 
     t_ref = time.perf_counter()
-    ref = Reference(config, *data, n_rounds=CHECK_ROUNDS)
-    readings = []
-    for seeds_k, got in kept:
-        grid = cell_grid(traffic, seeds_k)
-        want = ref.run(
-            w0_flat, [g[3] for g in grid], [g[1] for g in grid],
-            [g[2] for g in grid], [POLICIES.index(g[0]) for g in grid],
-        )
-        readings.append(check.compare(got, want, config["tie_margin"]))
+    readings = program.check(config, traffic, data, params0, kept)
     numbers = check.merge(readings)
     limits = config["limits"]
     correct, rows = check.verdict(numbers, limits)
     failed = sum(not check.verdict(r, limits)[0] for r in readings)
     log(f"reference over {len(kept)} sweeps in {time.perf_counter() - t_ref:.3f} s; "
-        f"cells compared {numbers['cells_compared']}, left out as tied "
-        f"{numbers['cells_tied']}")
+        + ", ".join(f"{k} {v}" for k, v in numbers.items() if k.startswith("cells_")))
 
     device = {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
@@ -312,6 +273,7 @@ def main(argv=None, *, t0: float | None = None, root: str = ROOT,
             red=red, config=config, traffic=traffic, chips=chips,
             n_cells=n_cells, rate=rate, compile_s=compile_s,
             window_compiles=window_compiles, device_kind=devices[0].device_kind,
+            program=program,
         )
         metrics = {}
         for m in bench["per_layer"]:

@@ -3,10 +3,12 @@
     PYTHONPATH=src JAX_PLATFORMS=cpu python3 -m pytest perfbench/tests -q
 
 They cover the trace reduction, the FLOP and byte counts, finding cells,
-configurations, traffic and metrics by name, the refusal to run without a
-TPU, and the check that decides ``correct``: a run of the program passes
-it, and runs with the control (the reference one precision below) or a
-fault planted under the timed path fail it.
+configurations, traffic, programs and metrics by name, the refusal to run
+without a TPU, and the check that decides ``correct``: a run of the
+program passes it, and runs with the control (the reference one precision
+below) or a fault planted under the timed path fail it. A toy second
+program, written into a scratch checkout, shows that a configuration the
+lattice does not run joins the benchmark as new files alone.
 """
 from __future__ import annotations
 
@@ -25,7 +27,9 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
 from perfbench import check, trace  # noqa: E402
-from perfbench.run import load_cell, load_metric  # noqa: E402
+from perfbench.run import load_cell, load_metric, load_program  # noqa: E402
+
+LATTICE = load_program(ROOT, "lattice")
 
 
 def _config(name):
@@ -130,7 +134,8 @@ def test_kernel_share_of_busy_time(reduction):
 def _ctx(red, **kw):
     base = dict(red=red, config=_config("logreg-mnist-n30"), traffic={},
                 chips=len(red.devices) if red else 1, n_cells=1000, rate=1.0,
-                compile_s=1.0, window_compiles=0, device_kind="TPU v5 lite")
+                compile_s=1.0, window_compiles=0, device_kind="TPU v5 lite",
+                program=LATTICE)
     base.update(kw)
     return SimpleNamespace(**base)
 
@@ -144,18 +149,17 @@ def test_readers_return_nothing_without_a_trace():
 # -- FLOP and byte counts ----------------------------------------------------
 
 def test_forward_flops_by_hand():
-    mfu = load_metric(ROOT, "round_mfu_pct")
     # conv0..3: 32²·9·3·32, 32²·9·32·64, 16²·9·64·128, 8²·9·128·128; fc1, out
     convs = (1024 * 9 * 3 * 32 + 1024 * 9 * 32 * 64 + 256 * 9 * 64 * 128
              + 64 * 9 * 128 * 128)
     cnn = 2 * (convs + 128 * 128 + 128 * 10)
     assert cnn == 96_176_640
-    assert mfu.forward_flops(CNN) == cnn
-    assert mfu.forward_flops(_config("logreg-mnist-n30")) == 15_680
+    assert LATTICE.forward_flops(CNN) == cnn
+    assert LATTICE.forward_flops(_config("logreg-mnist-n30")) == 15_680
     # per cell-round: 3 x fwd x 30 devices x batch 10, plus the eval's
     # 10,000 rows on 6 of 100 rounds (0, 20, 40, 60, 80 and the last)
     traffic = {"rounds": 100, "eval_every": 20}
-    assert mfu.flops_per_cell_round(_config("logreg-mnist-n30"), traffic) == (
+    assert LATTICE.flops_per_cell_round(_config("logreg-mnist-n30"), traffic) == (
         3 * 15_680 * 300 + 15_680 * 10_000 * 6 / 100)
 
 
@@ -183,6 +187,7 @@ def _tiny_root(tmp_path):
     (pb / "traffic").mkdir()
     os.symlink(os.path.join(ROOT, "src"), root / "src")
     os.symlink(os.path.join(ROOT, "perfbench", "metrics"), pb / "metrics")
+    os.symlink(os.path.join(ROOT, "perfbench", "programs"), pb / "programs")
     cfg = _config("logreg-mnist-n30")
     cfg.update(name="tiny", n_devices=6, n_scheduled=2, n_train=240, n_test=40,
                batch_size=4)
@@ -202,19 +207,37 @@ def _tiny_root(tmp_path):
     return root
 
 
-def test_new_cell_and_metric_are_found_by_name(tmp_path):
+def _repo_files() -> dict:
+    """mtime of every file of the repository's benchmark: BENCHMARK.json
+    and all under perfbench/ but bytecode caches."""
+    out = {"BENCHMARK.json": os.path.getmtime(os.path.join(ROOT, "BENCHMARK.json"))}
+    for dp, dns, fs in os.walk(os.path.join(ROOT, "perfbench")):
+        dns[:] = [d for d in dns if d != "__pycache__"]
+        for f in fs:
+            out[os.path.relpath(os.path.join(dp, f), ROOT)] = os.path.getmtime(
+                os.path.join(dp, f))
+    return out
+
+
+@pytest.fixture
+def repo_untouched():
+    """Fails the test if it added, removed or wrote any benchmark file of
+    the repository: what it adds goes to its scratch checkout."""
+    before = _repo_files()
+    yield
+    assert _repo_files() == before
+
+
+def test_new_cell_and_metric_are_found_by_name(tmp_path, repo_untouched):
     root = _tiny_root(tmp_path)
     os.unlink(root / "perfbench" / "metrics")
     shutil.copytree(os.path.join(ROOT, "perfbench", "metrics"), root / "perfbench" / "metrics")
     (root / "perfbench" / "metrics" / "new_metric.py").write_text(
         "def read(ctx):\n    return 42.0\n")
-    before = {p: os.path.getmtime(os.path.join(ROOT, "perfbench", p))
-              for p in os.listdir(os.path.join(ROOT, "perfbench"))}
     _, cell, config, traffic = load_cell(str(root), "tiny")
     assert (cell["traffic"], config["n_devices"], traffic["rounds"]) == ("tiny", 6, 4)
+    assert config.get("program") is None  # the lattice's
     assert load_metric(str(root), "new_metric").read(None) == 42.0
-    after = {p: os.path.getmtime(os.path.join(ROOT, "perfbench", p)) for p in before}
-    assert before == after
 
 
 # -- no chip, no result ------------------------------------------------------
@@ -260,12 +283,12 @@ def test_verdict_holds_every_number_to_its_limit():
                               "cells_compared": 0}, limits)[0]
 
 
-def _drive(root, capsys, program_cls=None):
-    from perfbench.run import Program, main
+def _drive(root, capsys, program_cls=None, workload="tiny"):
+    from perfbench.run import main
 
-    rc = main(["--workload", "tiny", "--seed", "3000000019", "--seconds", "0.2",
+    rc = main(["--workload", workload, "--seed", "3000000019", "--seconds", "0.2",
                "--trace", "0"], root=str(root), require_tpu=False,
-              program_cls=program_cls or Program)
+              program_cls=program_cls)
     assert rc == 0
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert list(out)[-1] == "checks"
@@ -328,3 +351,222 @@ def test_fault_under_the_timed_path_is_not_correct(tiny_root, capsys, monkeypatc
     out = _drive(tiny_root, capsys)
     reset_engine_cache()
     assert not out["correct"], out["checks"]
+
+
+# -- a program the lattice does not run, added as new files ------------------
+
+QUAD = '''"""A toy program: gradient descent on the quadratic 0.5 * sum(h * x**2),
+``rounds`` scanned steps a sweep, one cell per run seed, each starting from
+the initial state plus a normal draw of its seed."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+_TRACES = [0]
+
+
+def make_data(config):
+    return np.random.default_rng(config["data_seed"]).uniform(
+        0.5, 1.5, config["dim"]).astype(np.float32)
+
+
+def init_params(config, key):
+    return jax.random.normal(key, (config["dim"],))
+
+
+class Program:
+    def __init__(self, config, traffic, data, params0):
+        h, lr, rounds = jnp.asarray(data), config["lr"], traffic["rounds"]
+
+        def run(x0, seeds):
+            _TRACES[0] += 1
+
+            def cell(seed):
+                def step(x, _):
+                    x = x - lr * h * x
+                    return x, 0.5 * jnp.sum(h * x * x)
+                x = x0 + jax.random.normal(jax.random.PRNGKey(seed), x0.shape)
+                return jax.lax.scan(step, x, None, length=rounds)[1]
+            return jax.vmap(cell)(seeds)
+
+        self._run = jax.jit(run)
+        self.params0 = params0
+
+    def sweep(self, seeds):
+        return self._run(self.params0, jnp.asarray(seeds, jnp.int32))
+
+
+def n_cells(traffic):
+    return traffic["seeds"]
+
+
+def cell_rounds_per_sweep(traffic):
+    return traffic["seeds"] * traffic["rounds"]
+
+
+def kept(records):
+    return np.asarray(records)
+
+
+def counters():
+    return None, _TRACES[0]
+
+
+def release():
+    pass
+
+
+def check(config, traffic, data, params0, kept_list):
+    """The losses again, in float64 on the host, from the same draws."""
+    h = np.asarray(data, np.float64)
+    readings = []
+    for seeds, got in kept_list:
+        x = np.stack([
+            np.asarray(params0, np.float64)
+            + np.asarray(jax.random.normal(jax.random.PRNGKey(s), params0.shape))
+            for s in seeds
+        ])
+        want = []
+        for _ in range(traffic["rounds"]):
+            x = x - config["lr"] * h * x
+            want.append(0.5 * np.sum(h * x * x, axis=1))
+        want = np.stack(want, axis=1)
+        readings.append({"loss_gap": float(np.max(np.abs(got - want) / want)),
+                         "cells_compared": len(seeds)})
+    return readings
+
+
+def flops_per_cell_round(config, traffic):
+    return 6.0 * config["dim"]
+'''
+
+# each a fault planted in the toy: (the text replaced, its replacement)
+QUAD_FAULTS = {
+    "check": ("want.append(0.5 * np.sum", "want.append(np.sum"),
+    "answer": ("x = x - lr * h * x", "x = x - 0.5 * lr * h * x"),
+}
+
+
+def _toy_root(tmp_path, programs: dict):
+    """A checkout with one cell per program of ``programs`` (name ->
+    source), each with a configuration and traffic of its own, added as
+    new files beside the repository's BENCHMARK.json entries."""
+    root = tmp_path / "checkout"
+    pb = root / "perfbench"
+    for d in ("configs", "traffic", "programs"):
+        (pb / d).mkdir(parents=True)
+    os.symlink(os.path.join(ROOT, "src"), root / "src")
+    os.symlink(os.path.join(ROOT, "perfbench", "metrics"), pb / "metrics")
+    (pb / "traffic" / "toy.json").write_text(json.dumps({"seeds": 3, "rounds": 8}))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for name, source in programs.items():
+        if source is not None:
+            (pb / "programs" / f"{name}.py").write_text(source)
+        (pb / "configs" / f"{name}.json").write_text(json.dumps({
+            "program": name, "dim": 64, "lr": 0.1, "data_seed": 0,
+            "limits": {"loss_gap": 1e-4},
+        }))
+        bench["configs"].append({"name": name, "source": "test", "reduced": [],
+                                 "file": f"perfbench/configs/{name}.json", "why": "test"})
+        bench["workloads"].append({"name": name, "config": name, "traffic": "toy",
+                                   "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return root
+
+
+@pytest.fixture(scope="module")
+def toy_root(tmp_path_factory):
+    programs = {"quad": QUAD}
+    for fault, (old, new) in QUAD_FAULTS.items():
+        assert QUAD.count(old) == 1
+        programs[f"quad_{fault}"] = QUAD.replace(old, new)
+    programs["quad_missing"] = None
+    programs["quad_partial"] = QUAD.replace("def kept(", "def _kept(").replace(
+        "def release(", "def _release(")
+    return _toy_root(tmp_path_factory.mktemp("toy"), programs)
+
+
+def test_new_program_is_found_by_name_and_run_end_to_end(toy_root, capsys, repo_untouched):
+    out = _drive(toy_root, capsys, workload="quad")
+    assert out["correct"], out["checks"]
+    assert set(out["metrics"]) == {"cell_rounds_per_s", "peak_hbm_gib", "setup_s"}
+    assert all(m["value"] >= 0 for m in out["metrics"].values())
+    assert out["metrics"]["cell_rounds_per_s"]["value"] > 0
+    # the toy's own check decided `correct`, over the warm-up and window sweeps
+    assert [r["name"] for r in out["checks"]] == ["loss_gap"]
+    assert out["checks"][0]["value"] < 1e-5
+    assert out["attempted"] >= 2 and out["failed"] == 0
+
+
+@pytest.mark.parametrize("fault", sorted(QUAD_FAULTS))
+def test_new_program_with_a_fault_is_not_correct(toy_root, capsys, repo_untouched, fault):
+    out = _drive(toy_root, capsys, workload=f"quad_{fault}")
+    assert not out["correct"], out["checks"]
+    assert out["failed"] == out["attempted"]
+
+
+@pytest.mark.parametrize("workload, named", [
+    ("quad_missing", "quad_missing.py does not exist"),
+    ("quad_partial", "lacks kept, release"),
+])
+def test_missing_or_incomplete_program_exits_nonzero(toy_root, capsys, repo_untouched,
+                                                     workload, named):
+    from perfbench.run import main
+
+    rc = main(["--workload", workload, "--seed", "1", "--seconds", "0.2"],
+              root=str(toy_root), require_tpu=False)
+    out, err = capsys.readouterr()
+    assert rc != 0
+    assert out == ""
+    assert named in err
+
+
+# the per-layer metrics read in every cell, whatever its program
+LISTLESS = ("sweep_gap_ms", "compile_s", "window_compiles", "round_mfu_pct",
+            "aircomp_roofline_pct", "aircomp_busy_pct", "device_idle_pct")
+
+
+def test_listless_metrics_are_those_read_on_any_program():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert tuple(m["name"] for m in bench["per_layer"] if "workloads" not in m) == LISTLESS
+
+
+@pytest.mark.parametrize("traced", [False, True])
+@pytest.mark.parametrize("name", LISTLESS)
+def test_listless_reader_works_on_any_program(toy_root, repo_untouched, name, traced):
+    from jax.profiler import ProfileData
+
+    quad = load_program(str(toy_root), "quad")
+    _, _, config, traffic = load_cell(str(toy_root), "quad")
+    red = None
+    if traced:  # the toy runs no fused aggregation kernel
+        ops, spans = trace.events_of(ProfileData.from_text_proto(
+            XSPACE.replace("aircomp_fused", "dot")))
+        red = trace.reduce_events(ops, spans)
+    compile_s, compiles = quad.counters()
+    ctx = SimpleNamespace(
+        red=red, config=config, traffic=traffic, chips=1,
+        n_cells=quad.n_cells(traffic), rate=1234.5, compile_s=compile_s,
+        window_compiles=compiles, device_kind="TPU v5 lite", program=quad,
+    )
+    value = load_metric(ROOT, name).read(ctx)
+    assert value is None or (isinstance(value, (int, float)) and value == value)
+    if name.startswith("aircomp_"):
+        assert value is None
+    if name in ("device_idle_pct", "sweep_gap_ms"):
+        assert (value is not None) == traced
+
+
+@pytest.mark.parametrize("part", ["make_data", "init_params", "Program", "forward_flops"])
+def test_lattice_refuses_a_task_it_does_not_know(part):
+    config = dict(_config("logreg-mnist-n30"), task="transformer")
+    calls = {
+        "make_data": lambda: LATTICE.make_data(config),
+        "init_params": lambda: LATTICE.init_params(config, None),
+        "Program": lambda: LATTICE.Program(config, {}, None, None),
+        "forward_flops": lambda: LATTICE.forward_flops(config),
+    }
+    with pytest.raises(ValueError, match="transformer"):
+        calls[part]()
